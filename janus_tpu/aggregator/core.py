@@ -247,7 +247,7 @@ class TaskAggregator:
                 else:
                     # columnar validation, not scalar decode: the full
                     # Python decode was the measured upload bottleneck
-                    # (BASELINE.md served table)
+                    # (unverified link-era figure)
                     self.wire.validate_leader_share(payload)
         except (HpkeError, DecodeError, ValueError) as e:
             metrics.upload_decrypt_failure_counter.add()

@@ -2,7 +2,7 @@
 hangs & deadlines").
 
 `jax.block_until_ready` / a device fetch has no timeout: a wedged XLA
-dispatch (device hang, tunnel stall) parks the calling thread forever,
+dispatch (device hang) parks the calling thread forever,
 silently holding a job lease until TTL while the work it was doing is
 already dead. The accelerator must be treated as a failable peer —
 exactly like the helper behind the outbound circuit breaker.

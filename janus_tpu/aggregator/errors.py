@@ -1,4 +1,4 @@
-"""Aggregator error taxonomy -> DAP problem details.
+"""Aggregator error catalog -> DAP problem details.
 
 Equivalent of reference aggregator/src/aggregator/error.rs +
 problem_details.rs: typed errors that map to (HTTP status, problem

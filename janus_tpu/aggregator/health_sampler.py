@@ -65,7 +65,11 @@ def artifact_paths_from_config(common, aggregator=None) -> dict[str, str]:
     out = {}
     if aggregator is not None and getattr(aggregator, "upload_journal_path", None):
         out["upload_journal"] = aggregator.upload_journal_path
-    cache_dir = common.engine.compile_cache_dir or common.compilation_cache_dir
+    from ..config import resolve_compile_cache_dir
+
+    cache_dir, _ = resolve_compile_cache_dir(
+        common.engine.compile_cache_dir or common.compilation_cache_dir
+    )
     manifest = common.engine.shape_manifest_path
     if manifest is None and cache_dir:
         manifest = os.path.join(cache_dir, "shape_manifest.jsonl")

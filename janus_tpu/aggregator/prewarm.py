@@ -8,7 +8,7 @@ with synthetic data of exactly that geometry, so the trace happens and
 the persistent XLA compile cache is loaded BEFORE /readyz reports
 ready. Entries are warmed highest-recorded-cost first and bounded by a
 boot budget; the remainder continues on a background thread (role
-`engine_warm` in the profiler taxonomy), so one pathological manifest
+`engine_warm` in the profiler catalog), so one pathological manifest
 can delay readiness by at most the budget, never forever.
 
 The same warmer serves the quarantine canary (engine_cache._canary_loop):
@@ -183,19 +183,15 @@ class _Warmer:
         return bucket // 2 + 1
 
     def _batch(self, eng, n: int):
-        import numpy as np
-
-        from ..vdaf.testing import make_report_batch, random_measurements
+        from ..vdaf.testing import zero_report_batch
 
         key = (id(eng), n)
         got = self._batches.get(key)
         if got is None:
             base = self._base.get(id(eng))
             if base is None:
-                rng = np.random.default_rng(0xC01D)
-                base, _ = make_report_batch(
-                    eng.inst, random_measurements(eng.inst, 1, rng), seed=0xC01D
-                )
+                # host-built zero reports: no client shard compiles here
+                base = zero_report_batch(eng.inst, 1)
                 self._base[id(eng)] = base
             args = tuple(_tile_rows(a, n) for a in base)
             got = self._batches[key] = (args, {})

@@ -72,13 +72,13 @@ def _precompile(args, ds) -> None:
     deployment's first job then loads executables from disk in seconds
     instead of stalling minutes on the first jit per (task, bucket).
     The cache dir must match the binaries' CommonConfig
-    compilation_cache_dir (default ~/.cache/janus_tpu_xla)."""
+    compilation_cache_dir (JAX_COMPILATION_CACHE_DIR when set, else the
+    checkout's `.jax_cache`)."""
     import time
 
     from ..binary_utils import enable_compile_cache, warmup_engines
 
-    cache_dir = os.path.expanduser(args.compilation_cache_dir)
-    enable_compile_cache(cache_dir)
+    cache_dir, _ = enable_compile_cache(args.compilation_cache_dir)
     buckets = [int(b) for b in str(args.precompile).split(",") if b]
     for b in sorted(buckets):
         t0 = time.time()
@@ -131,9 +131,9 @@ def build_parser() -> argparse.ArgumentParser:
     )
     pt.add_argument(
         "--compilation-cache-dir",
-        default="~/.cache/janus_tpu_xla",
-        help="must match the aggregator binaries' "
-        "compilation_cache_dir (CommonConfig default)",
+        default=None,
+        help="must match the aggregator binaries' compilation_cache_dir "
+        "(default: JAX_COMPILATION_CACHE_DIR, else the checkout's .jax_cache)",
     )
 
     lt = sub.add_parser("list-tasks", help="list provisioned tasks")

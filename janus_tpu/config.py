@@ -9,6 +9,7 @@ Secrets (datastore keys) arrive via flags/env, never the YAML file
 
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass, field
 
 import yaml
@@ -26,6 +27,27 @@ from .ledger import LedgerConfig
 from .profiler import ProfilerConfig
 from .slo import SloEngineConfig
 from .trace import TraceConfiguration
+
+# The compile cache's default home: one fixed, git-ignored directory in
+# the checkout (a cache directory that moves never finds its entries).
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
+
+
+def resolve_compile_cache_dir(
+    configured: str | None = DEFAULT_COMPILE_CACHE_DIR,
+) -> tuple[str | None, str]:
+    """(directory, where it came from) of the persistent compile cache.
+    A set `JAX_COMPILATION_CACHE_DIR` always wins; otherwise the
+    configured directory (None = the cache is off)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env, "JAX_COMPILATION_CACHE_DIR"
+    if configured is None:
+        return None, "disabled"
+    path = os.path.expanduser(configured)
+    return path, "checkout default" if path == DEFAULT_COMPILE_CACHE_DIR else "config"
 
 
 @dataclass
@@ -132,8 +154,8 @@ class EngineConfig:
     # serialized-executable AOT cache (<compile cache dir>/aot): a
     # restarted process deserializes compiled engine programs instead
     # of re-tracing them — the layer that takes a warm restart from
-    # ~trace-per-program to ~tens of ms per program. JANUS_AOT_CACHE
-    # env overrides ("0" disables, a path relocates).
+    # ~trace-per-program to ~tens of ms per program. JANUS_AOT_CACHE=0
+    # disables it.
     aot_cache: bool = True
     # AOT-compile the manifest's recorded specializations at boot,
     # before /readyz reports ready (highest recorded cost first,
@@ -239,8 +261,9 @@ class CommonConfig:
     jax_platform: str | None = None
     # Persistent XLA compilation cache directory. First compile of a
     # (VDAF, step, batch-bucket) is minutes; with the cache a process
-    # restart reloads compiled executables in seconds. None disables.
-    compilation_cache_dir: str | None = "~/.cache/janus_tpu_xla"
+    # restart reloads compiled executables in seconds. None disables;
+    # a set JAX_COMPILATION_CACHE_DIR overrides (resolve_compile_cache_dir).
+    compilation_cache_dir: str | None = DEFAULT_COMPILE_CACHE_DIR
     # Warm the engines for every provisioned task at boot (trace+compile
     # the helper/leader steps for the smallest batch bucket) instead of
     # stalling the first request. Only the VDAF-hot-path binaries use it.
@@ -310,7 +333,7 @@ class CommonConfig:
                 d.get("health_check_listen_address", "0.0.0.0:9001")
             ),
             jax_platform=d.get("jax_platform"),
-            compilation_cache_dir=d.get("compilation_cache_dir", "~/.cache/janus_tpu_xla"),
+            compilation_cache_dir=d.get("compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR),
             warmup_engines_at_boot=bool(d.get("warmup_engines_at_boot", False)),
             warmup_buckets=tuple(int(b) for b in d.get("warmup_buckets", ())),
             health_sampler_interval_s=float(d.get("health_sampler_interval_secs", 15.0)),

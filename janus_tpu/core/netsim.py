@@ -17,7 +17,7 @@ client's point of view:
     "up"   = client -> upstream   (the leader's request bytes)
     "down" = upstream -> client   (the helper's response bytes)
 
-Toxic taxonomy (dicts, so chaos_run schedules read like YAML):
+Toxic catalog (dicts, so chaos_run schedules read like YAML):
 
     {"kind": "latency",   "latency_s": 0.05, "jitter_s": 0.02}
         sleep latency±jitter before forwarding each chunk

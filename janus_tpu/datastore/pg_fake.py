@@ -31,7 +31,7 @@ that, `docker-compose.pg.yaml` + JANUS_TEST_DATABASE_URL runs the same
 suite against a real server (conftest adds the "postgres" engine
 automatically when psycopg and the URL are present).
 
-Error taxonomy mirrors psycopg's: SerializationFailure and
+Error catalog mirrors psycopg's: SerializationFailure and
 DeadlockDetected subclass OperationalError, which subclasses Error.
 SQLite "database is locked" surfaces as OperationalError — the same
 retryable class a PG worker sees on a dropped connection.

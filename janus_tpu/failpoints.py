@@ -33,8 +33,8 @@ Actions:
     oom[:P]      raise a RESOURCE_EXHAUSTED-shaped error so the engine
                  OOM-recovery path (halved-bucket retry, host fallback)
                  takes over.
-    hang[:S]     park the calling thread — a wedged device dispatch /
-                 tunnel stall that never returns. S seconds when given;
+    hang[:S]     park the calling thread — a wedged device dispatch
+                 that never returns. S seconds when given;
                  default (0) parks FOREVER, released only by the
                  process stopper (release_hangs(), wired to SIGTERM) or
                  by re-configuring/disarming the registry. A timed park

@@ -56,7 +56,7 @@ from .metrics import task_id_label
 
 log = logging.getLogger(__name__)
 
-# Counter-name taxonomy (task_counters.counter_name). Rejections are
+# Counter-name catalog (task_counters.counter_name). Rejections are
 # per-reason: "rejected:<prepare error name>".
 ADMITTED = "admitted"
 AGGREGATED = "aggregated"

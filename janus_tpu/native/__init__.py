@@ -3,7 +3,9 @@
 The reference's host-side hot code is native Rust (the prio crate's XOF
 expansion and codec, SURVEY.md section 2.2); this package holds the TPU
 build's native equivalents. The shared library is compiled on first use
-with the system compiler and cached next to the sources; everything has
+with the system compiler and cached next to the sources under a name
+keyed on the hash of the source, so only a library built from the
+committed xof.c is ever loaded; everything has
 a pure-Python fallback so the framework still works where no compiler
 is available (`native.available()` reports which path is active).
 
@@ -18,6 +20,7 @@ Current contents:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import sys
@@ -28,7 +31,13 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "xof.c")
-_LIB_NAME = f"libjanus_native-{sys.implementation.cache_tag}.so"
+
+
+def _lib_name() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return f"libjanus_native-{sys.implementation.cache_tag}-{digest}.so"
+
 
 _lock = threading.Lock()
 _lib = None
@@ -63,13 +72,10 @@ def _load():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        lib_path = os.path.join(_DIR, _LIB_NAME)
         try:
-            if not os.path.exists(lib_path) or os.path.getmtime(
-                lib_path
-            ) < os.path.getmtime(_SRC):
-                if not _build(lib_path):
-                    return None
+            lib_path = os.path.join(_DIR, _lib_name())
+            if not os.path.exists(lib_path) and not _build(lib_path):
+                return None
             lib = ctypes.CDLL(lib_path)
         except OSError:
             return None

@@ -6,7 +6,7 @@ block in, 168+ out of the permutation kernel, re-read by the sampler —
 ~24 raw stream bytes per Field128 element that exist only to be reduced
 mod p and thrown away. At the north-star SumVec len=100k that stream is
 38.4 MB per report and is what capped the single-chip batch at 8
-(BASELINE.md "Roofline": the limiter is HBM *capacity*).
+(the limiter is HBM *capacity*; unverified link-era figure).
 
 This kernel fuses the whole expansion: each grid cell covers 8 reports
 x 128 counter blocks; the single-block counter-mode Keccak state is

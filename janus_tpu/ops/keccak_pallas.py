@@ -26,6 +26,7 @@ anyway); tests that need a different mode patch `_mode` directly.
 
 from __future__ import annotations
 
+import logging
 import os
 from functools import lru_cache
 
@@ -37,6 +38,8 @@ import numpy as np
 # authoritative copy (keccak_jax imports this module only lazily inside
 # keccak_f1600, so there is no import cycle).
 from ..vdaf.keccak_jax import _RC as _RC_U64, _ROT
+
+log = logging.getLogger(__name__)
 
 _RC = [int(x) for x in _RC_U64]
 
@@ -122,14 +125,38 @@ def _mode() -> str:
     Multi-device TPU processes run with kernels off: engine_cache binds
     jitted steps to a dp mesh there, and pallas_call has no SPMD
     partitioning rule — sharding it needs shard_map plumbing around
-    every call site (future work; single-chip is where the benchmarks
-    run today)."""
+    every call site (future work). That decision is logged once and
+    shown in the /statusz engine_cache section (status())."""
     flag = os.environ.get("JANUS_PALLAS")
     if flag == "0":
         return "off"
     if jax.default_backend() == "tpu":
-        return "tpu" if len(jax.devices()) == 1 else "off"
+        n = len(jax.devices())
+        if n == 1:
+            return "tpu"
+        log.warning(
+            "Pallas kernels OFF: this process sees %d TPU devices and the mesh "
+            "engines cannot partition a pallas_call; the scan path runs instead",
+            n,
+        )
+        return "off"
     return "interpret" if flag == "1" else "off"
+
+
+def status() -> dict:
+    """Kernel mode and why, for /statusz and chip_smoke.py."""
+    mode = _mode()
+    if os.environ.get("JANUS_PALLAS") == "0":
+        reason = "JANUS_PALLAS=0"
+    elif jax.default_backend() != "tpu":
+        reason = f"{jax.default_backend()} backend"
+        if mode == "interpret":
+            reason += " (interpret)"
+    elif mode == "off":
+        reason = f"{len(jax.devices())} TPU devices: no SPMD rule for pallas_call"
+    else:
+        reason = "one TPU device"
+    return {"mode": mode, "reason": reason}
 
 
 # Below this many state columns the relayout into [50, R, 128] u32
@@ -142,6 +169,16 @@ def enabled(n_columns: int | None = None) -> bool:
     if _mode() == "off":
         return False
     return n_columns is None or n_columns >= MIN_COLUMNS
+
+
+def on_tpu(kernel, fallback):
+    """kernel() in a program lowered for a TPU, fallback() in one
+    lowered for another platform: a TPU process's clients shard on the
+    host CPU, where a Mosaic kernel cannot run. Interpret mode runs the
+    kernel everywhere."""
+    if _mode() == "interpret":
+        return kernel()
+    return jax.lax.platform_dependent(tpu=kernel, default=fallback)
 
 
 @lru_cache(maxsize=None)

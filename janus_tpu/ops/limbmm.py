@@ -6,7 +6,7 @@ report a [W x calls] @ [calls x chunk] product. The reference computes
 the equivalent per report on CPU inside `prio`
 (aggregator/src/aggregator/aggregation_job_driver.rs:329-402); round-4
 ran it on the VPU as u64-emulated limb multiplies, which the roofline
-pinned at ~14% of envelope (BASELINE.md) — the admitted instruction-mix
+pinned at ~14% of envelope (unverified link-era figure) — the admitted instruction-mix
 headroom. This module moves those multiplies to the MXU, the unit with
 ~40x the integer throughput, by decomposing field elements into 7-bit
 limbs and contracting with int8 x int8 -> int32 `dot_general`s:

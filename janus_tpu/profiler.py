@@ -119,7 +119,7 @@ def fold_component(s: str) -> str:
 
 
 # ---------------------------------------------------------------------------
-# Thread-role taxonomy: prefix match over the names the subsystems
+# Thread-role catalog: prefix match over the names the subsystems
 # assign where their threads are created (docs/OBSERVABILITY.md carries
 # the same table). First match wins — order longest/most specific first.
 # ---------------------------------------------------------------------------

@@ -563,7 +563,7 @@ def BUILTIN_SLOS() -> list[SloDefinition]:
             description=(
                 "the device path is healthy: no new hung dispatches, no "
                 "watchdog-parked threads, and no engine resident off the "
-                "device (quarantined / host_fallback / timed_fallback)"
+                "device (quarantined / host_fallback)"
             ),
             objective=0.99,
             signal=ConditionSignal(
@@ -584,7 +584,7 @@ def BUILTIN_SLOS() -> list[SloDefinition]:
                             "janus_engine_backend",
                             compile_matchers(
                                 {
-                                    "state": "~(quarantined|host_fallback|timed_fallback)"
+                                    "state": "~(quarantined|host_fallback)"
                                 }
                             ),
                         ),

@@ -3,7 +3,7 @@
     python scripts/debug_bundle.py --url http://127.0.0.1:9001 \\
         [--url http://127.0.0.1:9002 ...] [--config-file cfg.yaml] \\
         [--journal-dir /var/janus/journal] \\
-        [--shape-manifest ~/.cache/janus_tpu_xla/shape_manifest.jsonl] \\
+        [--shape-manifest .jax_cache/shape_manifest.jsonl] \\
         [--out bundle.tar.gz]
 
 Snapshots every introspection endpoint of one or several binaries'

@@ -563,7 +563,7 @@ def _flp_query_batched_mm(
     wire fold runs as one limb-decomposed int8 matmul
     (ops/limbmm.fold_contract) instead of u64-emulated VPU multiplies.
     This is the round-5 answer to the instruction-mix headroom
-    (BASELINE.md roofline): the contraction over gadget calls is where
+    (unverified link-era figure): the contraction over gadget calls is where
     ~all the query's multiplies live, and the MXU does it at ~40x the
     VPU's integer rate. Replaces the reference's per-report CPU query
     (aggregation_job_driver.rs:329-402) at every chunked length.
@@ -729,7 +729,7 @@ def flp_query_streamed(
     order differs but field addition is exact mod p), with peak memory
     O(group) instead of O(input_len): the expanded share never fully
     materializes. This is what lifts the SumVec len=100k single-chip
-    batch cap (BASELINE.md roofline: the limiter was HBM capacity).
+    batch cap (the limiter was HBM capacity; unverified link-era figure).
     Replaces the reference's per-report query loop
     (aggregation_job_driver.rs:329-402) at north-star lengths.
     """

@@ -110,8 +110,14 @@ def keccak_f1600(state, rounds: int | None = None):
     state = tuple(jnp.asarray(x, dtype=U64) for x in state)
     n = int(np.prod(state[0].shape)) if state[0].shape else 1
     if keccak_pallas.enabled(n):
-        return keccak_pallas.keccak_f1600_pallas(state, rounds)
+        return keccak_pallas.on_tpu(
+            lambda: keccak_pallas.keccak_f1600_pallas(state, rounds),
+            lambda: _keccak_scan(state, rounds),
+        )
+    return _keccak_scan(state, rounds)
 
+
+def _keccak_scan(state, rounds: int):
     def body(a, rc):
         return _keccak_round(a, rc), None
 
@@ -163,9 +169,8 @@ def shake128_squeeze_lanes(msg_lanes, out_blocks: int):
 # Sponge chains past this many blocks run as NESTED scans (an outer
 # scan of _SCAN_CHUNK-length inner scans): a single flat lax.scan goes
 # wildly superlinear past ~32k trip counts on the TPU runtime
-# (measured: 1.9 s at 32k blocks vs 209 s at 152k — BASELINE.md "Draft
-# mode"), which round 4 mistook for an inherent cost and capped the
-# draft device gate on. The chunking is value-neutral: the same
+# (1.9 s at 32k blocks vs 209 s at 152k — unverified link-era figure),
+# which round 4 mistook for an inherent cost and capped the draft device gate on. The chunking is value-neutral: the same
 # sequential permutation chain, same output blocks.
 _SCAN_CHUNK = 4096
 
@@ -304,12 +309,15 @@ def _single_block_keccak(lane_cols, out_lanes: int = 25):
 
     shape = lane_cols[0].shape
     n = int(np.prod(shape)) if shape else 1
-    if out_lanes < 25 and keccak_pallas.enabled(n):
-        return keccak_pallas.keccak_single_block_pallas(
-            lane_cols, out_lanes, rounds=KECCAK_ROUNDS
-        )
     zeros = jnp.zeros_like(lane_cols[0])
     state = tuple(lane_cols) + (zeros,) * 4
+    if out_lanes < 25 and keccak_pallas.enabled(n):
+        return keccak_pallas.on_tpu(
+            lambda: keccak_pallas.keccak_single_block_pallas(
+                lane_cols, out_lanes, rounds=KECCAK_ROUNDS
+            ),
+            lambda: _keccak_scan(state, KECCAK_ROUNDS)[:out_lanes],
+        )
     return keccak_f1600(state)
 
 
@@ -487,12 +495,22 @@ def expand_field_vec(jf, prefix_parts, prefix_len_bytes: int, batch: int, length
     that stream block; the caller is responsible for block-aligning the
     element range (Field128: 7 elements per block).
     """
-    from ..ops import expand_pallas
+    from ..ops import expand_pallas, keccak_pallas
 
     assert prefix_len_bytes % 8 == 0  # lane-aligned framing (xof.py)
     blocks = sample_count_blocks(jf, length)
+
+    def unfused():
+        out = ctr_stream_lanes(
+            prefix_parts, prefix_len_bytes, batch, blocks, ctr_offset=block_offset
+        )
+        return sample_field_vec(jf, out, length)
+
     if expand_pallas.enabled(jf, blocks):
-        prefix = _assemble_segments(prefix_parts, prefix_len_bytes // 8, batch)
-        return expand_pallas.expand_f128(prefix, blocks, length, block_offset=block_offset)
-    out = ctr_stream_lanes(prefix_parts, prefix_len_bytes, batch, blocks, ctr_offset=block_offset)
-    return sample_field_vec(jf, out, length)
+
+        def fused():
+            prefix = _assemble_segments(prefix_parts, prefix_len_bytes // 8, batch)
+            return expand_pallas.expand_f128(prefix, blocks, length, block_offset=block_offset)
+
+        return keccak_pallas.on_tpu(fused, unfused)
+    return unfused()

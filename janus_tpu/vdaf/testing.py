@@ -78,6 +78,7 @@ def make_wire_reports(
     helper_hpke_config,
     time,
     seed: int = 0,
+    batch_args=None,
 ):
     """Device-shard a batch and assemble full DAP Report messages.
 
@@ -85,7 +86,8 @@ def make_wire_reports(
     for the whole batch), then each report is HPKE-sealed and framed
     exactly as client.Client.prepare_report does per report
     (reference client/src/lib.rs:212-260). Used by load generators and
-    the served-mode bench.
+    the served-mode bench. `batch_args` passes an already sharded batch
+    (make_report_batch's step args for these measurements).
     """
     from ..core.hpke import HpkeApplicationInfo, Label, hpke_seal
     from ..messages import (
@@ -106,7 +108,9 @@ def make_wire_reports(
         from .reference import SparsePublicShare
 
         _, block_idx = sparse_compact_batch(inst, measurements)
-    args, _ = make_report_batch(inst, measurements, seed=seed)
+    args = batch_args
+    if args is None:
+        args, _ = make_report_batch(inst, measurements, seed=seed)
     nonce_lanes, public_parts, leader_meas, leader_proof, blind0, helper_seed, blind1 = args
     n = nonce_lanes.shape[0]
     meas_rows = encode_field_rows(p3.jf, leader_meas)
@@ -152,6 +156,37 @@ def make_wire_reports(
         )
         reports.append(Report(metadata, public_share, leader_ct, helper_ct))
     return reports
+
+
+def zero_report_batch(inst: VdafInstance, batch: int):
+    """All-zero step args with the shapes and dtypes make_report_batch
+    returns, built on the host: the shapes come from an abstract trace
+    of the shard (jax.eval_shape), so nothing is compiled or run on a
+    device. The reports do not verify; engine warm-up only needs the
+    programs they compile."""
+    import jax
+
+    p3 = prio3_batched(inst)
+    n_seeds = 4 if p3.uses_joint_rand else 2
+    inp = tuple(
+        jax.ShapeDtypeStruct((batch, p3.circ.input_len), np.uint64) for _ in range(p3.jf.LIMBS)
+    )
+    sh = jax.eval_shape(
+        p3.shard,
+        inp,
+        jax.ShapeDtypeStruct((batch, 2), np.uint64),
+        jax.ShapeDtypeStruct((batch, n_seeds, 2), np.uint64),
+    )
+    zeros = lambda t: jax.tree_util.tree_map(lambda a: np.zeros(a.shape, a.dtype), t)  # noqa: E731
+    return (
+        np.zeros((batch, 2), np.uint64),
+        zeros(sh["public_parts"]),
+        zeros(sh["leader_meas"]),
+        zeros(sh["leader_proof"]),
+        zeros(sh["blind0"]),
+        zeros(sh["helper_seed"]),
+        zeros(sh["blind1"]),
+    )
 
 
 def make_report_batch(inst: VdafInstance, measurements, seed: int = 0, shard_chunk: int = 0):

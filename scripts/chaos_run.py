@@ -67,7 +67,7 @@ A third scenario, `--scenario device_hang`, proves the DEADLINE-AWARE
 DEVICE PATH (docs/ROBUSTNESS.md "Device hangs & deadlines"): the real
 aggregation job driver binary runs with `engine.dispatch=hang,count=1`
 armed — its first device dispatch wedges forever, exactly like a hung
-XLA dispatch / tunnel stall. Invariants:
+XLA dispatch. Invariants:
 
   - the hung step never outlives its lease: the dispatch watchdog
     abandons the dispatch within the lease budget and the job steps
@@ -195,13 +195,11 @@ def _free_port() -> int:
 
 def _driver_cfg(
     path, db, health_port, ttl_s, cooldown_s, extra: str = "",
-    cache_dir: str = "~/.cache/janus_tpu_xla",
 ):
     cfg = (
         f"database: {{url: {db}}}\n"
         f'health_check_listen_address: "127.0.0.1:{health_port}"\n'
         "jax_platform: cpu\n"
-        f"compilation_cache_dir: {cache_dir}\n"
         "min_job_discovery_delay_secs: 0.1\n"
         "max_job_discovery_delay_secs: 0.5\n"
         f"worker_lease_duration_secs: {ttl_s}\n"
@@ -1380,15 +1378,16 @@ def run_cold_start(
             port,
             600,
             1.5,
-            cache_dir=cache_dir,
             extra="engine:\n  prewarm_boot_budget_secs: 300\n",
         )
+        # the A/B needs its own cold cache per pair, so it is the one
+        # place that points the binary at another cache directory
         drv = _spawn_driver(
             cfg,
             key,
             os.path.join(tmp, f"driver-{idx}-{label}.log"),
             None,
-            extra_env={"JANUS_SHAPE_MANIFEST": manifest},
+            extra_env={"JANUS_SHAPE_MANIFEST": manifest, "JAX_COMPILATION_CACHE_DIR": cache_dir},
         )
         boot: dict = {"label": label}
         try:
@@ -1879,13 +1878,12 @@ def run_resident(
             leader_ds.run_tx(lambda tx, t=leader_task: tx.put_task(t), "provision")
             helper_ds.run_tx(lambda tx, t=helper_task: tx.put_task(t), "provision")
             tasks[name] = (leader_task, collector_kp, task_vdaf)
-        # warm into the DRIVER's default persistent cache dir (NOT
-        # enable_compile_cache's own default — a different path) so the
-        # subprocess loads compiled programs from disk instead of
+        # warm into the driver's persistent cache dir (the same default
+        # resolution the binary uses) so the subprocess loads compiled programs from disk instead of
         # paying cold compiles against the lease watchdog: the sparse
         # leader_init compile alone (~15 s on CPU) would wedge past the
         # 6 s budget and spuriously quarantine the sparse engine
-        enable_compile_cache(os.path.expanduser("~/.cache/janus_tpu_xla"))
+        enable_compile_cache()
         warmup_engines(leader_ds)
 
         creator = AggregationJobCreator(

@@ -116,7 +116,7 @@ def main():
     # "library_full" ~= "planar_full"; "contiguous_level0" preserves
     # the pre-r5 contiguous-leaf baseline this change was measured
     # against (245 ms library vs 176 ms planar on this config,
-    # 2026-08-01 — recorded in BASELINE.md).
+    # 2026-08-01; unverified link-era figure).
     timeit("library_full", current)
     timeit("contiguous_level0", level0_only_current)
     timeit("planar_full", planar_level0)

@@ -250,3 +250,33 @@ print(json.dumps({{'first_job_s': time.time() - t0}}))
     assert probe.returncode == 0, probe.stderr.decode()[-2000:]
     stat = _json.loads(probe.stdout.decode().strip().splitlines()[-1])
     assert stat["first_job_s"] < 30, stat
+
+
+@pytest.mark.parametrize(
+    "env,configured,want",
+    [
+        ("/srv/jax-cache", None, ("/srv/jax-cache", "JAX_COMPILATION_CACHE_DIR")),
+        ("/srv/jax-cache", "/var/cache/janus", ("/srv/jax-cache", "JAX_COMPILATION_CACHE_DIR")),
+        (None, "/var/cache/janus", ("/var/cache/janus", "config")),
+        (None, None, (None, "disabled")),
+        (None, "default", None),  # the checkout's fixed .jax_cache
+    ],
+    ids=["env", "env-beats-config", "config", "disabled", "checkout-default"],
+)
+def test_compile_cache_dir_resolution(monkeypatch, env, configured, want):
+    """A set JAX_COMPILATION_CACHE_DIR is the only directory used;
+    without it, the configured one or the checkout's fixed .jax_cache."""
+    import pathlib
+
+    from janus_tpu.config import DEFAULT_COMPILE_CACHE_DIR, resolve_compile_cache_dir
+
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    if configured == "default":
+        repo = pathlib.Path(__file__).resolve().parent.parent
+        assert DEFAULT_COMPILE_CACHE_DIR == str(repo / ".jax_cache")
+        assert resolve_compile_cache_dir() == (DEFAULT_COMPILE_CACHE_DIR, "checkout default")
+    else:
+        assert resolve_compile_cache_dir(configured) == want

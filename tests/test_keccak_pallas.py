@@ -100,3 +100,61 @@ def test_single_block_kernel_matches_general(monkeypatch):
         assert len(got) == out_lanes
         for i in range(out_lanes):
             assert (np.asarray(got[i]) == np.asarray(want[i])).all(), i
+
+
+def _kernel_site(name: str):
+    """One kernel call site of keccak_jax, past its size threshold."""
+    from janus_tpu.fields.jfield import JF128
+
+    rng = np.random.default_rng(11)
+
+    def lanes(n):
+        return tuple(
+            jnp.asarray(rng.integers(0, 1 << 63, size=(256, 128), dtype=np.uint64))
+            for _ in range(n)
+        )
+
+    if name == "permutation":
+        state = lanes(25)
+        return lambda: kj.keccak_f1600(state)
+    if name == "single_block":
+        rate = lanes(21)
+        return lambda: kj._single_block_keccak(rate, out_lanes=2)
+    seeds = jnp.asarray(rng.integers(0, 1 << 63, size=(2, 2), dtype=np.uint64))
+    parts = [(0, bytes(range(16))), (2, seeds)]
+    return lambda: kj.expand_field_vec(JF128, parts, 32, 2, 500)
+
+
+@pytest.mark.parametrize("site", ["permutation", "single_block", "expand"])
+def test_kernels_give_way_in_programs_lowered_for_the_host(monkeypatch, site):
+    """A TPU process's clients shard under jax.default_device(<cpu>):
+    what they trace is lowered for the CPU, where a Mosaic kernel cannot
+    run. With the kernels on, such a program runs the scan path
+    (lax.platform_dependent) and agrees with the kernels-off program."""
+    fn = _kernel_site(site)
+    monkeypatch.setattr(kp, "_mode", lambda: "off")
+    want = jax.tree_util.tree_leaves(jax.jit(fn)())
+    monkeypatch.setattr(kp, "_mode", lambda: "tpu")
+    with jax.default_device(jax.devices("cpu")[0]):
+        got = jax.tree_util.tree_leaves(jax.jit(fn)())
+    assert len(got) <= len(want)
+    for w, g in zip(want, got):
+        assert (np.asarray(w) == np.asarray(g)).all()
+
+
+def test_multi_device_tpu_turns_kernels_off_visibly(monkeypatch, caplog):
+    monkeypatch.undo()  # the real gate, not this module's interpret-mode patch
+    monkeypatch.delenv("JANUS_PALLAS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [None] * 4)
+    kp._mode.cache_clear()
+    try:
+        with caplog.at_level("WARNING", logger=kp.__name__):
+            assert kp._mode() == "off"
+        assert "Pallas kernels OFF" in caplog.text
+        assert kp.status() == {
+            "mode": "off",
+            "reason": "4 TPU devices: no SPMD rule for pallas_call",
+        }
+    finally:
+        kp._mode.cache_clear()

@@ -286,9 +286,6 @@ def test_mesh_subprocess_bit_identity_forced_4dev(monkeypatch):
     env["XLA_FLAGS"] = f"{flags} --xla_force_host_platform_device_count=4".strip()
     env.pop("JANUS_MESH_DP", None)
     env.pop("JANUS_MESH_SP", None)
-    env.setdefault(
-        "JAX_COMPILATION_CACHE_DIR", os.path.expanduser("~/.cache/jax_comp_cache")
-    )
     script = (
         "import sys; sys.path.insert(0, %r); sys.path.insert(0, %r)\n"
         % (REPO, os.path.join(REPO, "tests"))

@@ -36,7 +36,7 @@ from janus_tpu.profiler import (
 
 def test_thread_role_covers_every_named_thread_family():
     """Every thread family the codebase creates maps to its documented
-    role — a rename at a creation site without a taxonomy update is a
+    role — a rename at a creation site without a catalog update is a
     test failure, not a silent 'other'."""
     expected = {
         # step pipeline (ThreadPoolExecutor appends -0, -1, ...)

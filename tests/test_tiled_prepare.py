@@ -231,6 +231,37 @@ def test_feasibility_model_basics(monkeypatch):
     assert fz.device_memory_budget() == 12345
 
 
+class _FakeDevice:
+    def __init__(self, platform, stats):
+        self.platform = platform
+        self.device_kind = f"fake {platform}"
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize(
+    "platform,stats,want",
+    [
+        ("cpu", None, None),  # the CPU has no budget: uncapped
+        ("tpu", {"bytes_limit": 16 << 30, "peak_bytes_in_use": 0}, 16 << 30),
+        ("tpu", {}, RuntimeError),  # never plan against an assumed size
+    ],
+    ids=["cpu-uncapped", "tpu-memory-stats", "tpu-no-stats-error"],
+)
+def test_device_memory_budget_by_platform(monkeypatch, platform, stats, want):
+    from janus_tpu.vdaf import feasibility as fz
+
+    monkeypatch.delenv("JANUS_HBM_BUDGET", raising=False)
+    dev = _FakeDevice(platform, stats)
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError, match="no memory budget"):
+            fz.device_memory_budget(dev)
+    else:
+        assert fz.device_memory_budget(dev) == want
+
+
 def test_draft_device_gate_consults_budget():
     """vdaf.draft_jax device support is gated on the feasibility bound,
     not just MAX_STREAM_BLOCKS (r6 tentpole)."""

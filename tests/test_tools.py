@@ -118,7 +118,8 @@ def test_bench_dry_run_smoke():
     rec = json.loads(line)
     assert rec["metric"] == "dry_run"
     fz = rec["feasibility"]
-    assert fz["row_bytes"] > 0 and fz["budget_bytes"] > 0
+    # the CPU reports no memory budget: the model plans uncapped there
+    assert fz["row_bytes"] > 0 and fz["budget_bytes"] is None
     smoke = rec["oom_fallback_smoke"]
     assert smoke["halved_retry_ok"] is True
     assert smoke["host_fallback_ok"] is True
