@@ -378,7 +378,6 @@ def run_served(inst, n_reports: int, job_size: int) -> dict:
             "device_lane_self_pct": prof_doc["roles"]
             .get("device_lane", {})
             .get("self_pct", 0.0),
-            "us_per_report": _prof.DEVICE_COST.us_per_report(),
             "boot_total_s": _prof.BOOT.snapshot().get("total_s"),
         }
         return {
@@ -635,13 +634,13 @@ def _sparse_scatter_smoke() -> dict:
     and the pending-delta resident_merge — asserting the released
     aggregate is bit-identical to the dense oracle computed by expanding
     the plaintext measurements on host. Also proves the scatter path
-    actually ran (engine scatter counters + a scatter_merge cost-ledger
-    op with nonzero rows)."""
+    actually ran (the engine's scatter rows and
+    janus_engine_scatter_rows_total both nonzero)."""
     import numpy as np
 
+    from janus_tpu import metrics
     from janus_tpu.aggregator.engine_cache import EngineCache
     from janus_tpu.messages import Duration, Interval, Time
-    from janus_tpu.profiler import DEVICE_COST
     from janus_tpu.vdaf.registry import VdafInstance, circuit_for
     from janus_tpu.vdaf.testing import (
         make_report_batch,
@@ -691,10 +690,7 @@ def _sparse_scatter_smoke() -> dict:
         for x, y in zip(recs[0]["share"], recs1[0]["share"])
     ]
     resident_identical = resident == want
-    ledger = DEVICE_COST.status()["entries"]
-    scatter_rows = sum(
-        e["rows"] for e in ledger if e["op"] == "scatter_merge" and e["vdaf"] == inst.kind
-    )
+    scatter_rows = metrics.engine_scatter_rows_total.get(vdaf=inst.kind)
     return {
         "classic_identical": classic_identical,
         "resident_identical": resident_identical,
@@ -926,9 +922,8 @@ _SNAPSHOT_PREFIXES = (
     "janus_database_",
     "janus_datastore_",
     "janus_tx_retries",
-    # continuous profiler + device cost ledger + boot timeline (ISSUE 13)
+    # continuous profiler + boot timeline (ISSUE 13)
     "janus_profiler_",
-    "janus_device_cost_",
     "janus_boot_",
 )
 
@@ -1764,7 +1759,6 @@ def _observability_smoke() -> dict:
             "profile_roles": profile_roles,
             "debug_boot_ok": debug_boot_ok,
             "statusz_profile_present": "profile" in statusz,
-            "statusz_device_cost_present": "device_cost" in statusz,
             # conservation ledger (ISSUE 20): statusz section + live
             # /debug/ledger document, books balanced on the smoke task
             "statusz_ledger_present": "ledger" in statusz,
@@ -3582,11 +3576,9 @@ def main() -> None:
         the compact width PLUS the gather/scatter-add of every verified
         report's blocks into one dense logical len-1M resident
         accumulator — the full serving device path, timed end to end.
-        µs/report comes from the device cost ledger's scatter_merge op;
-        the resident HBM figure is the one dense logical row the
+        The resident HBM figure is the one dense logical row the
         accumulator owns regardless of report count."""
         from janus_tpu.aggregator.engine_cache import EngineCache
-        from janus_tpu.profiler import DEVICE_COST
         from janus_tpu.vdaf.registry import circuit_for
         from janus_tpu.vdaf.testing import sparse_compact_batch
         from janus_tpu.vdaf.wire import flat_scatter_indices
@@ -3647,7 +3639,6 @@ def main() -> None:
             "resident_hbm_bytes": circ.logical_length * eng.p3.jf.LIMBS * 8,
             "scatter_rows": eng._scatter_rows,
             "block_occupancy": eng._sparse_last_occupancy,
-            "us_per_report": DEVICE_COST.us_per_report().get("scatter_merge"),
             "mesh_fallback_reason": eng.mesh_fallback_reason,
         }
 

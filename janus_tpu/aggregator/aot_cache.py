@@ -17,7 +17,8 @@ Keying: blobs are named by a digest over (jax version, backend
 platform + device count, the HOST target-machine fingerprint — CPU
 feature flags, see below — the engine identity — vdaf config + a
 verify key digest, since single-task programs close over the key as a
-trace constant — the jit variant name, the mesh geometry
+trace constant — the jit variant name and the module name lowered
+from it, the mesh geometry
 `(dp, sp, device count)` for mesh programs, and the argument avals
 (shape + dtype tree)). Anything the digest misses — a jax upgrade
 changing the wire format, a corrupted blob — surfaces as a
@@ -198,6 +199,9 @@ def engine_base(
             json.dumps(inst_dict, sort_keys=True, separators=(",", ":")),
             hashlib.sha256(verify_key).hexdigest()[:16],
             name,
+            # the lowered module's name (EngineCache._jit): blobs from
+            # before programs were named (all `jit_step`) never load
+            "module:jit_" + name,
             "mesh:%dx%d/%d" % mesh if mesh is not None else "single",
         )
     )

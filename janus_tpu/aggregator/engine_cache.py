@@ -566,13 +566,12 @@ class MeshDispatchQueue:
     correctness but became the throughput ceiling: it woke waiters in
     arbitrary order (starvation under contention) and hid the
     cross-engine serialization cost inside each caller's dispatch wall
-    time, invisible to the cost ledger.
+    time.
 
     The queue keeps the invariant — exactly ONE thread (the
     "mesh-dispatch" lane, profiled under the device_lane role) performs
     every mesh enqueue — and adds what a lock cannot: FIFO fairness,
-    janus_mesh_dispatch_* queue-depth/wait-time metrics, and a
-    cost-ledger row per mesh program. Only the ENQUEUE is serialized;
+    and janus_mesh_dispatch_* queue-depth/wait-time metrics. Only the ENQUEUE is serialized;
     execution stays async on the devices, so concurrent jobs keep
     coalescing and pipelining safely. Exceptions (OOM recovery depends
     on them) re-raise in the submitting thread, original object intact
@@ -640,7 +639,6 @@ class MeshDispatchQueue:
 
     def _run(self, q) -> None:
         from .. import metrics
-        from ..profiler import DEVICE_COST
 
         while True:
             item = q.get()
@@ -650,9 +648,7 @@ class MeshDispatchQueue:
                 depth = self._depth
                 if wait > self._stats["max_wait_s"]:
                     self._stats["max_wait_s"] = wait
-                first = (item.vdaf, item.program) not in self._seen
-                if first:
-                    self._seen.add((item.vdaf, item.program))
+                self._seen.add((item.vdaf, item.program))
             metrics.mesh_dispatch_queue_depth.set(float(depth))
             metrics.mesh_dispatch_wait_seconds.observe(wait)
             t0 = time.monotonic()
@@ -669,19 +665,6 @@ class MeshDispatchQueue:
                         self._stats["errors"] += 1
                 metrics.mesh_dispatch_busy_seconds.add(dt)
                 metrics.mesh_dispatch_total.add(program=item.program or "unknown")
-                if item.vdaf:
-                    # per-mesh-program ledger row: the lane's enqueue
-                    # wall (first call of a program = trace+compile or
-                    # AOT deserialize; distinct from the engine's own
-                    # per-specialization rows, which include queue wait)
-                    DEVICE_COST.record(
-                        item.vdaf,
-                        f"mesh:{item.program}",
-                        0,
-                        "compile" if first else "execute",
-                        dt,
-                        dispatches=1,
-                    )
                 item.done.set()
 
     def status(self) -> dict:
@@ -1040,13 +1023,13 @@ class EngineCache:
         # dispatch per (op, bucket) is the compile; OOM events feed the
         # /statusz engine-cache section
         self._dispatched_buckets: set[tuple[str, int]] = set()
-        # finer first-dispatch tracking for the device cost ledger:
-        # keyed by the jit specialization (variant name + bucket) the
-        # call site reports, so a classic-aggregate compile after the
-        # resident path warmed the same row bucket — or a new
-        # agg_buckets_{kk} program at an already-seen bucket — still
-        # books as phase="compile" in ITS ledger row
-        self._ledger_dispatched: set[tuple] = set()
+        # finer first-dispatch tracking for the persisted shape
+        # manifest: keyed by the jit specialization (variant name +
+        # bucket) the call site reports, so a classic-aggregate compile
+        # after the resident path warmed the same row bucket — or a new
+        # agg_buckets_{kk} program at an already-seen bucket — is still
+        # recorded as a specialization of its own
+        self._specializations_dispatched: set[tuple] = set()
         self._dispatch_track_lock = threading.Lock()
         self.oom_history: deque = deque(maxlen=16)
         self._publish_state()
@@ -1083,21 +1066,28 @@ class EngineCache:
         n: int,
         b: int,
         elapsed_s: float,
-        ledger_op: str | None = None,
+        manifest_op: str | None = None,
         compile_key: tuple | None = None,
     ) -> None:
         """Per-dispatch accounting: throughput counters, padding-waste
         gauge, and the first-call-per-(op, bucket) compile histogram —
         jax.jit compiles synchronously on the first call of a shape
         bucket, so that call's wall time IS the cold-start cost
-        OBSERVABILITY.md used to describe only in prose."""
+        OBSERVABILITY.md used to describe only in prose. `manifest_op`
+        names the shape-manifest entry finer than the engine counters
+        (the resident aggregate_pending path shares op="aggregate" in
+        janus_engine_dispatches_total but one dispatch covers k
+        buckets) and `compile_key` carries the variant name the call
+        site jitted, so first-dispatch tracking follows the real
+        specialization, not the engine-metric (op, bucket)
+        approximation."""
         from .. import metrics
 
         metrics.engine_dispatches_total.add(op=op)
         metrics.engine_rows_total.add(n, op=op)
         if b > 0:
             metrics.engine_batch_fill_ratio.set(n / b, op=op)
-        lkey = compile_key if compile_key is not None else (ledger_op or op, b)
+        lkey = compile_key if compile_key is not None else (manifest_op or op, b)
         if self.mesh is not None:
             # mesh specializations are keyed by geometry too: the shape
             # manifest must never hand a (dp, sp) program to a boot with
@@ -1108,39 +1098,18 @@ class EngineCache:
             first = (op, b) not in self._dispatched_buckets
             if first:
                 self._dispatched_buckets.add((op, b))
-            ledger_first = lkey not in self._ledger_dispatched
-            if ledger_first:
-                self._ledger_dispatched.add(lkey)
+            new_specialization = lkey not in self._specializations_dispatched
+            if new_specialization:
+                self._specializations_dispatched.add(lkey)
         if first:
             metrics.engine_compile_seconds.observe(elapsed_s, op=op, bucket=str(b))
-        # per-dispatch device cost ledger (ISSUE 13): the first call of
-        # a jit specialization IS the trace+compile, later calls are
-        # execute; rows ride along so the µs/report attribution has a
-        # denominator. `ledger_op` splits ledger rows finer than the
-        # engine counters (the resident aggregate_pending path shares
-        # op="aggregate" in janus_engine_dispatches_total but one
-        # dispatch covers k buckets) and `compile_key` carries the
-        # variant name the call site jitted, so compile-vs-execute
-        # classification tracks the real specialization, not the
-        # engine-metric (op, bucket) approximation.
-        from ..profiler import DEVICE_COST
-
-        DEVICE_COST.record(
-            self.inst.kind,
-            ledger_op or op,
-            b,
-            "compile" if ledger_first else "execute",
-            elapsed_s,
-            rows=n,
-            dispatches=1,
-        )
-        if ledger_first:
+        if new_specialization:
             # persisted shape manifest (ISSUE 14): the first dispatch
             # of a specialization IS the cold-start cost a restarted
             # process would pay again — record it so the boot prewarm
             # can compile exactly this set before /readyz flips ready
             shape_manifest.record_dispatch(
-                self.inst, ledger_op or op, b, lkey, elapsed_s, rows=n
+                self.inst, manifest_op or op, b, lkey, elapsed_s, rows=n
             )
 
     # Per-call row cap for joining a shared round; absolute round row
@@ -1189,6 +1158,9 @@ class EngineCache:
 
     def _jit(self, name: str, fn, in_shardings=None, out_shardings=None):
         if name not in self._jits:
+            # the program's name: the lowered module, and the trace's
+            # `XLA Modules` line, read jit_<name>
+            fn.__name__ = fn.__qualname__ = name
             kwargs = {}
             if self.mesh is not None:
                 if in_shardings is not None:
@@ -1337,40 +1309,19 @@ class EngineCache:
     QUARANTINE_CANARY_TIMEOUT_SECS = float(os.environ.get("JANUS_CANARY_TIMEOUT_S", "30.0"))
     QUARANTINE_CANARY_MAX_DELAY_SECS = 60.0
 
-    # Supervised regions whose wall time the device cost ledger
-    # attributes as a whole (no finer-grained span/dispatch accounting
-    # inside them): the resident fetches are pure d2h waits. The init/
-    # aggregate labels are deliberately absent — their phases are split
-    # inside the closure (_record_dispatch + the put/fetch span hooks).
-    _LEDGER_SUPERVISED_PHASES = {
-        "fetch_resident": "d2h",
-        "resident_fetch": "d2h",
-        "resident_delta_fetch": "d2h",
-    }
-
     def _supervised(self, label: str, fn):
         """Route a device-touching closure through the process dispatch
         watchdog under the AMBIENT deadline (job drivers: lease bound;
         helper handlers: propagated request budget — core/deadline.py).
         No ambient deadline = direct call: one contextvar read, the
         bench --dry-run `watchdog_overhead` record keeps it honest."""
-        phase = self._LEDGER_SUPERVISED_PHASES.get(label)
-        t0 = time.monotonic() if phase is not None else 0.0
-        try:
-            return device_watchdog.WATCHDOG.run(
-                fn,
-                deadline=current_deadline(),
-                label=label,
-                vdaf=self.inst.kind,
-                on_hang=self._quarantine_on_hang,
-            )
-        finally:
-            if phase is not None:
-                from ..profiler import DEVICE_COST
-
-                DEVICE_COST.record(
-                    self.inst.kind, label, 0, phase, time.monotonic() - t0
-                )
+        return device_watchdog.WATCHDOG.run(
+            fn,
+            deadline=current_deadline(),
+            label=label,
+            vdaf=self.inst.kind,
+            on_hang=self._quarantine_on_hang,
+        )
 
     def _quarantine_on_hang(self, label: str) -> None:
         """Watchdog hang hook: open the device circuit. Serving moves
@@ -1935,10 +1886,10 @@ class EngineCache:
 
         # one supervised region for the whole pipeline: every chunk's
         # block_until_ready/dispatch/fetch can park on a wedged device
-        # the dominant chunk bucket keys the cost ledger's per-bucket
-        # row for the whole pipelined pass (the tail chunk may pad to a
-        # smaller bucket; its share of the one put/fetch span can't be
-        # split out)
+        # the dominant chunk bucket labels the put/fetch spans of the
+        # whole pipelined pass (the tail chunk may pad to a smaller
+        # bucket; its share of the one put/fetch span can't be split
+        # out)
         chunk_b = bucket_size(min(n, C))
 
         def device_call():
@@ -2189,7 +2140,7 @@ class EngineCache:
                 n,
                 bucket_size(n),
                 time.monotonic() - t_disp,
-                ledger_op="scatter_merge",
+                manifest_op="scatter_merge",
                 compile_key=("scatter_merge", bucket_size(n)),
             )
             metrics.engine_scatter_rows_total.add(n_rows, vdaf=self.inst.kind)
@@ -2293,7 +2244,7 @@ class EngineCache:
                 n_rows,
                 bucket_size(n_rows),
                 time.monotonic() - t_disp,
-                ledger_op="aggregate_pending",
+                manifest_op="aggregate_pending",
                 # the traced program specializes on the padded bucket
                 # COUNT kk (agg_buckets_{kk}), not just the row bucket
                 compile_key=("aggregate_pending", kk, bucket_size(n_rows)),
@@ -2458,8 +2409,8 @@ class EngineCache:
     def _sparse_slot_value(self, slot, deltas: "SparsePendingDeltas", j: int):
         """Scatter-add bucket j's report blocks into the slot's dense
         logical accumulator (zeros for a fresh slot / a raw delta
-        fetch). One device dispatch, booked as a scatter_merge cost-
-        ledger row; feeds the scatter metrics."""
+        fetch). One device dispatch, recorded as a scatter_merge
+        specialization; feeds the scatter metrics."""
         from .. import metrics
 
         L = deltas.logical_len
@@ -2475,7 +2426,7 @@ class EngineCache:
             n_rows,
             bucket_size(len(sel)),
             time.monotonic() - t_disp,
-            ledger_op="scatter_merge",
+            manifest_op="scatter_merge",
             compile_key=("scatter_merge", bucket_size(len(sel))),
         )
         metrics.engine_scatter_rows_total.add(n_rows, vdaf=self.inst.kind)

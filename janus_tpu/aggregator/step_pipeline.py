@@ -50,7 +50,10 @@ Correctness invariants:
     the serial path does.
 
 Observability: janus_step_pipeline_stage_seconds{stage},
-janus_step_pipeline_queue_depth{stage}, janus_device_lane_busy_ratio,
+janus_step_pipeline_queue_depth{stage}, the time each job waits in a
+stage's queue and for a staging-window slot
+(janus_step_pipeline_queue_wait_seconds{stage}; the staging wait is
+also the `pipeline.staging_wait` span), janus_device_lane_busy_ratio,
 janus_step_pipeline_overlap_total, a `step_pipeline` /statusz section,
 and a per-job "job.step" flight-recorder digest observation (the bench
 served phase reads p50/p95 from it).
@@ -278,11 +281,16 @@ class StepPipeline:
         with self._lock:
             self._queued[stage] += 1
             metrics.step_pipeline_queue_depth.set(self._queued[stage], stage=stage)
+        # the enqueue stamp: the wait ends on the stage's own thread,
+        # so it is an observation from here, not a span
+        t_enqueued = time.monotonic()
         try:
             if stage == STAGE_DEVICE:
-                self.lane.submit(self._run_stage, stage, fn, job, label)
+                self.lane.submit(self._run_stage, stage, fn, job, label, t_enqueued)
             else:
-                self._pools[stage].submit(self._run_stage, stage, fn, job, label)
+                self._pools[stage].submit(
+                    self._run_stage, stage, fn, job, label, t_enqueued
+                )
         except RuntimeError as e:
             # pool shut down mid-chain (close() raced a straggler):
             # surface instead of silently stranding the lease
@@ -292,8 +300,14 @@ class StepPipeline:
             self._fail(job, e)
 
     # --- stage execution -----------------------------------------------
-    def _run_stage(self, stage: str, fn, job: _PipelinedStep, label: str | None) -> None:
+    def _run_stage(
+        self, stage: str, fn, job: _PipelinedStep, label: str | None, t_enqueued: float
+    ) -> None:
         from ..trace import use_traceparent
+
+        metrics.step_pipeline_queue_wait_seconds.observe(
+            time.monotonic() - t_enqueued, stage=stage
+        )
 
         # only the REAL helper-RTT stage counts as an in-flight HTTP
         # leg for the overlap proof: a "classic" step body on the HTTP
@@ -432,7 +446,7 @@ class StepPipeline:
         job.trace_context = jobrow.trace_context
         job.deadline = driver._lease_deadline(acquired)
 
-        from ..trace import use_traceparent
+        from ..trace import span, use_traceparent
 
         with use_traceparent(job.trace_context), deadline_mod.deadline_scope(
             job.deadline
@@ -451,7 +465,8 @@ class StepPipeline:
                 return (STAGE_COMMIT, self._stage_classic, STAGE_CLASSIC)
             # blocks this read worker while prefetch_depth jobs already
             # hold unconsumed staged columns — the staged-memory bound
-            self._staging_window.acquire()
+            with span("pipeline.staging_wait"):
+                self._staging_window.acquire()
             job.staging_permit = True
             st = driver.stage_init(acquired, task, jobrow, rows, reports)
             job.state = st

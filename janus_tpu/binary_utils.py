@@ -225,9 +225,10 @@ class BoundedThreadingHTTPServer(ThreadingHTTPServer):
 
 # ---------------------------------------------------------------------------
 # On-demand profiler capture (POST /debug/profile?seconds=N): one
-# window runs jax.profiler.trace (device timeline, loadable in
-# Perfetto/TensorBoard) plus a temporary host Chrome-trace writer, and
-# answers with the artifact paths. Guarded: concurrent captures 409,
+# window runs jax.profiler.trace (loadable in Perfetto/TensorBoard;
+# the program's spans ride it on the device ops' clock, trace.span)
+# plus a temporary host Chrome-trace writer, and answers with the
+# artifact paths. Guarded: concurrent captures 409,
 # the window is clamped.
 # ---------------------------------------------------------------------------
 
@@ -243,9 +244,11 @@ class ProfileBusy(RuntimeError):
 def capture_profile(seconds: float, out_dir: str | None = None) -> dict:
     """Open a capture window of `seconds` (clamped to
     [PROFILE_MIN_SECONDS, PROFILE_MAX_SECONDS]); raises ProfileBusy if
-    one is already open. Returns the artifact paths: the host
-    Chrome-trace JSON always; the jax.profiler trace dir when the
-    profiler starts (absent on backends without one)."""
+    one is already open. Returns the artifact paths: the jax.profiler
+    trace dir when the profiler starts (absent on backends without
+    one), which holds the host spans and the device ops on one clock;
+    the host Chrome-trace JSON always, on this process's own clock, for
+    backends without a profiler."""
     import tempfile
     import time as _time
 

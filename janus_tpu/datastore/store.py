@@ -2286,8 +2286,16 @@ class Datastore:
         retry/idempotency story the chaos harness proves). The error
         action raises TxConflict, i.e. a retryable conflict: run_tx's
         own retry loop must absorb injected commit failures the same
-        way it absorbs real serialization failures."""
+        way it absorbs real serialization failures.
+
+        Each attempt runs as spans `datastore.lock_wait` (connect plus
+        BEGIN until it returns), `datastore.body` (fn) and
+        `datastore.commit`, and each backoff as `datastore.retry_wait`,
+        all with tx=name; they feed
+        janus_database_transaction_phase_seconds{tx, phase} and tile
+        the janus_database_transaction_duration_seconds observation."""
         from .. import failpoints, metrics
+        from ..trace import span
 
         def _inj() -> TxConflict:
             return TxConflict(f"injected conflict (failpoint, tx={name})")
@@ -2303,14 +2311,16 @@ class Datastore:
             try:
                 # inside the try: a failed (re)connect is a retryable
                 # connection-class failure, not an immediate crash out
-                conn = self._connect()
-                self._begin(conn)
-                failpoints.hit_scoped("datastore.tx_begin", name, error_factory=_inj)
-                tx = self._tx_obj(conn)
-                result = fn(tx)
-                failpoints.hit_scoped("datastore.commit", name, error_factory=_inj)
-                conn.commit()
-                failpoints.hit_scoped("datastore.post_commit", name, error_factory=_inj)
+                with span("datastore.lock_wait", tx=name):
+                    conn = self._connect()
+                    self._begin(conn)
+                with span("datastore.body", tx=name):
+                    failpoints.hit_scoped("datastore.tx_begin", name, error_factory=_inj)
+                    result = fn(self._tx_obj(conn))
+                with span("datastore.commit", tx=name):
+                    failpoints.hit_scoped("datastore.commit", name, error_factory=_inj)
+                    conn.commit()
+                    failpoints.hit_scoped("datastore.post_commit", name, error_factory=_inj)
                 elapsed = _time.monotonic() - start
                 metrics.tx_duration.observe(elapsed, tx=name)
                 if 0 < self.slow_tx_warn_s < elapsed:
@@ -2356,7 +2366,8 @@ class Datastore:
                     self.supervisor.record_failure(e)
                 if kind == "fatal" or attempt == self.MAX_RETRIES - 1:
                     raise
-                _time.sleep(self._retry_sleep_s(attempt))
+                with span("datastore.retry_wait", tx=name):
+                    _time.sleep(self._retry_sleep_s(attempt))
             except BaseException:
                 if conn is not None:
                     try:

@@ -475,6 +475,17 @@ http_request_duration = REGISTRY.histogram(
 tx_duration = REGISTRY.histogram(
     "janus_database_transaction_duration_seconds", "datastore transaction latency"
 )
+# sub-millisecond phases (an uncontended BEGIN, a WAL commit) need
+# buckets below the default 1 ms floor
+FINE_BUCKETS = (0.0001, 0.00025, 0.0005) + DEFAULT_BUCKETS
+tx_phase_duration = REGISTRY.histogram(
+    "janus_database_transaction_phase_seconds",
+    "datastore transaction time by tx name and phase: lock_wait (connect "
+    "plus BEGIN until it returns), body, commit, retry_wait (backoff "
+    "sleep); a successful run_tx's phases sum to its "
+    "janus_database_transaction_duration_seconds observation",
+    buckets=FINE_BUCKETS,
+)
 tx_retries_total = REGISTRY.counter(
     "janus_tx_retries_total",
     "datastore transaction attempts that failed retryably, by tx name and "
@@ -697,6 +708,20 @@ step_pipeline_queue_depth = REGISTRY.gauge(
     "janus_step_pipeline_queue_depth",
     "jobs handed to a pipeline stage and not yet executing, by stage",
 )
+step_pipeline_queue_wait_seconds = REGISTRY.histogram(
+    "janus_step_pipeline_queue_wait_seconds",
+    "time a job waited between being handed to a pipeline stage and the "
+    'stage starting, by stage (stage="read|device|http|commit"), and '
+    'the read worker\'s wait for a staging-window slot (stage="staging")',
+    buckets=FINE_BUCKETS,
+)
+aggregate_init_stage_seconds = REGISTRY.histogram(
+    "janus_aggregate_init_stage_seconds",
+    "helper aggregate-init request time by stage "
+    '(stage="hpke_stage|replay_tx|columnar|accumulate|write_tx"), fed by '
+    "the helper.<stage> spans",
+    buckets=FINE_BUCKETS,
+)
 device_lane_busy_ratio = REGISTRY.gauge(
     "janus_device_lane_busy_ratio",
     "fraction of wall time the pipeline's serialized device lane spent "
@@ -855,7 +880,7 @@ slo_burn_rate = REGISTRY.gauge(
     "14.4x over 1h and tickets at 6x over 6h)",
 )
 
-# --- always-on continuous profiler + device cost ledger + boot
+# --- always-on continuous profiler + boot
 # timeline (janus_tpu/profiler.py; ISSUE 13, docs/OBSERVABILITY.md
 # "Continuous profiling") ---
 profiler_samples_total = REGISTRY.counter(
@@ -872,18 +897,6 @@ profiler_overhead_ratio = REGISTRY.gauge(
     "measured fraction of wall time the sampling profiler spends in its "
     "own passes over the retained windows (0 while off; alert well "
     "before the 2% budget)",
-)
-device_cost_seconds_total = REGISTRY.counter(
-    "janus_device_cost_seconds_total",
-    "cumulative device-path wall time attributed by the per-dispatch "
-    'cost ledger, by op and phase (phase="compile|execute|h2d|d2h"; '
-    "per-(vdaf, op, bucket) detail is the /statusz device_cost section)",
-)
-device_cost_us_per_report = REGISTRY.gauge(
-    "janus_device_cost_us_per_report",
-    "live microseconds of device-path wall time per report row, by op "
-    "and phase (an op's cumulative phase seconds over its cumulative "
-    "rows — what the device-lane busy time BUYS per report)",
 )
 engine_prewarm_total = REGISTRY.counter(
     "janus_engine_prewarm_total",
@@ -1216,10 +1229,10 @@ set_replica_identity()
 
 
 def _register_span_bridges() -> None:
-    """Bind the engine span names to janus_engine_dispatch_seconds via
-    the span->metric bridge (trace.register_span_metric): a span exit
-    IS the histogram observation, so the trace timeline and the metric
-    cannot drift apart. The vdaf label rides the span args."""
+    """Bind span names to their histograms via the span->metric bridge
+    (trace.register_span_metric): a span exit IS the histogram
+    observation, so the trace timeline and the metric cannot drift
+    apart. Labels named in arg_labels ride the span args."""
     from .trace import register_span_metric
 
     for op in ("helper_init", "leader_init"):
@@ -1255,6 +1268,20 @@ def _register_span_bridges() -> None:
         labels={"op": "aggregate", "phase": "dispatch"},
         arg_labels=("vdaf",),
     )
+    # datastore run_tx phases (the tx label rides the span args)
+    for phase in ("lock_wait", "body", "commit", "retry_wait"):
+        register_span_metric(
+            f"datastore.{phase}", tx_phase_duration, labels={"phase": phase}, arg_labels=("tx",)
+        )
+    # the step pipeline's read worker blocked on the staging window
+    register_span_metric(
+        "pipeline.staging_wait", step_pipeline_queue_wait_seconds, labels={"stage": "staging"}
+    )
+    # the helper's aggregate-init stages
+    for stage in ("hpke_stage", "replay_tx", "columnar", "accumulate", "write_tx"):
+        register_span_metric(
+            f"helper.{stage}", aggregate_init_stage_seconds, labels={"stage": stage}
+        )
 
 
 _register_span_bridges()

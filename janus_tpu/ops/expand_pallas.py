@@ -243,6 +243,7 @@ def _call(p_lanes: int, b8: int, nb: int, tile_blocks: int, interpret: bool, rou
         in_specs=[off_spec, in_spec],
         out_specs=out_spec,
         interpret=interpret,
+        name="expand_f128",
     )
 
 

@@ -200,6 +200,7 @@ def _call(rows: int, interpret: bool, rounds: int = 24):
         in_specs=[spec],
         out_specs=spec,
         interpret=interpret,
+        name="keccak_f1600",
     )
 
 
@@ -267,6 +268,7 @@ def _call_single(rows: int, interpret: bool, out_lanes: int, rounds: int = 24):
         in_specs=[in_spec],
         out_specs=out_spec,
         interpret=interpret,
+        name="keccak_single_block",
     )
 
 

@@ -1,7 +1,7 @@
 """Always-on continuous profiling (docs/OBSERVABILITY.md "Continuous
 profiling").
 
-Three coordinated parts, all low-overhead enough to run in every
+Two coordinated parts, both low-overhead enough to run in every
 production binary:
 
   1. **Sampling wall-clock profiler** (`SamplingProfiler`): a daemon
@@ -19,16 +19,7 @@ production binary:
      (`janus_profiler_overhead_ratio`) — the overhead claim is a
      metric, not a promise.
 
-  2. **Per-dispatch device cost ledger** (`DeviceCostLedger`): every
-     supervised device region in the engine cache reports its wall time
-     here, split by phase — `compile` (first call of an (op, bucket)),
-     `execute` (dispatch), `h2d`/`d2h` (transfers) — keyed by
-     (vdaf, op, bucket) with dispatch and row counts. The derived
-     µs-per-report table (`janus_device_cost_us_per_report{op,phase}`)
-     gives the PR 8 lane-busy ratio its denominator: what the busy time
-     *buys* per report.
-
-  3. **Boot-phase timeline** (`BootTimeline`): janus_main records named
+  2. **Boot-phase timeline** (`BootTimeline`): janus_main records named
      bring-up phases (imports → config → backend init → datastore →
      engine_warm_manifest (shape-manifest load) → engine_warm (the
      boot-budget AOT prewarm + legacy warmup) → listener up) as one
@@ -478,154 +469,6 @@ def profile_json() -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Per-dispatch device cost ledger
-# ---------------------------------------------------------------------------
-
-COST_PHASES = ("compile", "execute", "h2d", "d2h")
-
-
-class DeviceCostLedger:
-    """Cumulative device-path cost per (vdaf, op, bucket), split by
-    phase, with dispatch and row counts — fed by the engine cache's
-    choke points (`_record_dispatch` for compile/execute + rows, the
-    put/fetch span hooks for h2d/d2h, the supervised resident fetches).
-    Derives the live `janus_device_cost_us_per_report{op,phase}` table:
-    for an op, phase seconds summed over (vdaf, bucket) divided by the
-    op's total rows."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        # {(vdaf, op, bucket): {"dispatches": n, "rows": n, <phase>_s...}}
-        self._entries: dict[tuple, dict] = {}
-        self._op_rows: dict[str, int] = {}
-        self._op_phase_s: dict[tuple[str, str], float] = {}
-
-    def record(
-        self,
-        vdaf: str,
-        op: str,
-        bucket: int,
-        phase: str,
-        seconds: float,
-        rows: int = 0,
-        dispatches: int = 0,
-    ) -> None:
-        if phase not in COST_PHASES:
-            raise ValueError(f"unknown cost phase {phase!r}")
-        from . import metrics
-
-        key = (str(vdaf), str(op), int(bucket))
-        with self._lock:
-            ent = self._entries.get(key)
-            if ent is None:
-                ent = self._entries[key] = {
-                    "dispatches": 0,
-                    "rows": 0,
-                    **{f"{p}_s": 0.0 for p in COST_PHASES},
-                }
-            ent["dispatches"] += dispatches
-            ent["rows"] += rows
-            ent[f"{phase}_s"] += seconds
-            self._op_rows[op] = self._op_rows.get(op, 0) + rows
-            self._op_phase_s[(op, phase)] = (
-                self._op_phase_s.get((op, phase), 0.0) + seconds
-            )
-            op_rows = self._op_rows[op]
-            gauge_updates = (
-                [
-                    (p, self._op_phase_s.get((op, p), 0.0))
-                    for p in COST_PHASES
-                ]
-                if op_rows > 0
-                else []
-            )
-        metrics.device_cost_seconds_total.add(seconds, op=op, phase=phase)
-        for p, total_s in gauge_updates:
-            metrics.device_cost_us_per_report.set(
-                total_s / op_rows * 1e6, op=op, phase=p
-            )
-
-    def us_per_report(self) -> dict:
-        """{op: {phase: µs/report}} for ops with recorded rows (the
-        bench rider and the statusz attribution table)."""
-        with self._lock:
-            out: dict = {}
-            for (op, phase), s in self._op_phase_s.items():
-                rows = self._op_rows.get(op, 0)
-                if rows > 0:
-                    out.setdefault(op, {})[phase] = round(s / rows * 1e6, 3)
-            return {op: dict(sorted(v.items())) for op, v in sorted(out.items())}
-
-    def status(self) -> dict:
-        """The /statusz `device_cost` section."""
-        with self._lock:
-            entries = [
-                {
-                    "vdaf": vdaf,
-                    "op": op,
-                    "bucket": bucket,
-                    "dispatches": ent["dispatches"],
-                    "rows": ent["rows"],
-                    **{
-                        f"{p}_s": round(ent[f"{p}_s"], 6)
-                        for p in COST_PHASES
-                    },
-                }
-                for (vdaf, op, bucket), ent in sorted(self._entries.items())
-            ]
-        return {"entries": entries, "us_per_report": self.us_per_report()}
-
-    def reset_for_tests(self) -> None:
-        with self._lock:
-            self._entries.clear()
-            self._op_rows.clear()
-            self._op_phase_s.clear()
-
-
-DEVICE_COST = DeviceCostLedger()
-
-
-# h2d/d2h wall time rides the existing engine put/fetch spans via the
-# span-hook registry (trace.register_span_hook): the span boundaries
-# ARE the transfer boundaries (engine_cache keeps the blocking
-# conversions inside them), so the ledger and the Chrome trace measure
-# the same thing by construction. The `bucket` span arg (added at the
-# engine call sites) keys the per-bucket row of the table.
-_TRANSFER_SPANS = {
-    "engine.helper_init.put": ("helper_init", "h2d"),
-    "engine.helper_init.fetch": ("helper_init", "d2h"),
-    "engine.leader_init.put": ("leader_init", "h2d"),
-    "engine.leader_init.put_all_async": ("leader_init", "h2d"),
-    "engine.leader_init.fetch": ("leader_init", "d2h"),
-    "engine.leader_init.fetch_seed": ("leader_init", "d2h"),
-    "engine.leader_init.fetch_ver": ("leader_init", "d2h"),
-    "engine.leader_init.fetch_part": ("leader_init", "d2h"),
-}
-
-
-def _register_transfer_hooks() -> None:
-    from .trace import register_span_hook
-
-    def make_hook(op: str, phase: str):
-        def hook(dur_s: float, args: dict) -> None:
-            try:
-                bucket = int(args.get("bucket") or 0)
-            except (TypeError, ValueError):
-                bucket = 0
-            DEVICE_COST.record(
-                str(args.get("vdaf", "")), op, bucket, phase, dur_s
-            )
-
-        return hook
-
-    for name, (op, phase) in _TRANSFER_SPANS.items():
-        register_span_hook(name, make_hook(op, phase))
-
-
-_register_transfer_hooks()
-
-
-# ---------------------------------------------------------------------------
 # Boot-phase timeline
 # ---------------------------------------------------------------------------
 
@@ -712,9 +555,8 @@ def boot_snapshot() -> dict:
     return BOOT.snapshot()
 
 
-# /statusz sections: the profiler summary and the device-cost table on
-# every binary (registered at import — binary_utils imports this
-# module, so every health listener carries them; both answer
-# well-formed empty/disabled documents before anything runs)
+# /statusz section: the profiler summary on every binary (registered
+# at import — binary_utils imports this module, so every health
+# listener carries it; it answers a well-formed disabled document
+# before anything runs)
 register_status_provider("profile", lambda: PROFILER.status())
-register_status_provider("device_cost", DEVICE_COST.status)

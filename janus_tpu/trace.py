@@ -5,8 +5,12 @@ Equivalent of the reference's tracing subscriber installation
 from config or the JANUS_LOG env var (the RUST_LOG analog), and a
 **Chrome trace-file layer** (trace.rs:68-71): host-side spans —
 request handlers, job steps, engine calls — written as Chrome
-trace-event JSON, loadable in chrome://tracing or Perfetto alongside
-the device-side `jax.profiler.trace` output (docs/OBSERVABILITY.md).
+trace-event JSON on this process's own clock (docs/OBSERVABILITY.md).
+
+While a `jax.profiler` session is recording, every `span()` is also a
+`jax.profiler.TraceAnnotation` around its body, so the program's spans
+land on the host plane of the same `.xplane.pb` as the device ops, on
+the profiler's clock, with their threads and nesting.
 """
 
 from __future__ import annotations
@@ -23,6 +27,8 @@ import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass
+
+from jax.profiler import TraceAnnotation
 
 # span-id generation: uniqueness, not unpredictability (no urandom
 # syscall); a module-level instance so the span() hot path pays neither
@@ -543,13 +549,6 @@ def use_context(ctx):
 
 _span_metrics: dict[str, tuple] = {}
 
-# span name -> [fn(dur_s, args)] side-channel hooks: the device cost
-# ledger (janus_tpu/profiler.py) attributes the engine put/fetch spans'
-# wall time to its h2d/d2h phases through these, so the ledger and the
-# trace timeline measure the same boundaries by construction. A hook
-# must never raise into the span exit path.
-_span_hooks: dict[str, list] = {}
-
 
 def register_span_metric(
     span_name: str, histogram, labels: dict | None = None, arg_labels: tuple = ()
@@ -560,22 +559,7 @@ def register_span_metric(
     _span_metrics[span_name] = (histogram, dict(labels or {}), tuple(arg_labels))
 
 
-def register_span_hook(span_name: str, fn) -> None:
-    """Call `fn(dur_s, args)` on every exit of span `span_name`
-    (in addition to any register_span_metric binding)."""
-    _span_hooks.setdefault(span_name, []).append(fn)
-
-
 def _bridge_span(name: str, dur_s: float, args: dict, trace_id=None) -> None:
-    hooks = _span_hooks.get(name)
-    if hooks is not None:
-        for fn in hooks:
-            try:
-                fn(dur_s, args)
-            except Exception:
-                logging.getLogger(__name__).exception(
-                    "span hook for %s failed", name
-                )
     reg = _span_metrics.get(name)
     if reg is None:
         return
@@ -839,13 +823,22 @@ def span(name: str, **args):
     register_span_metric also record their duration into the bound
     histogram on exit. An exception exiting the span is recorded as an
     `error=<ExcType>` attribute on every emitted event and counted in
-    janus_span_errors_total{name} — then re-raised."""
+    janus_span_errors_total{name} — then re-raised.
+
+    While a jax.profiler session records, the body also runs inside a
+    TraceAnnotation of the same name carrying the trace id, so the span
+    is on the device timeline's clock; otherwise the only cost is one
+    is_enabled() check."""
     parent = _trace_ctx.get()
     trace_id = parent[0] if parent else _span_rng.getrandbits(128)
     span_id = _span_rng.getrandbits(64)
     token = _trace_ctx.set((trace_id, span_id))
     w = _chrome_writer
     ox = _otlp_exporter
+    ann = None
+    if TraceAnnotation.is_enabled():
+        ann = TraceAnnotation(name, trace_id=_hex(trace_id, 32))
+        ann.__enter__()
     t0 = time.perf_counter_ns()
     e0 = time.time_ns()
     err_name = None
@@ -856,12 +849,14 @@ def span(name: str, **args):
         raise
     finally:
         t1 = time.perf_counter_ns()
+        if ann is not None:
+            ann.__exit__(None, None, None)
         _trace_ctx.reset(token)
         if err_name is not None:
             args["error"] = err_name  # kwargs dict is per-call: safe to mutate
             _count_span_error(name)
         dur_s = (t1 - t0) / 1e9
-        if _span_metrics or _span_hooks:
+        if _span_metrics:
             _bridge_span(name, dur_s, args, trace_id)
         _flight_recorder.record(
             name, trace_id, span_id, parent[1] if parent else None,
@@ -895,7 +890,7 @@ def record_operation(name: str, dur_s: float, **args) -> None:
     which the bench's served phase reads for the p50/p95 aggregation-
     job-step SLO — must still see one observation per stepped job."""
     trace_id = _span_rng.getrandbits(128)
-    if _span_metrics or _span_hooks:
+    if _span_metrics:
         # the synthesized trace id still resolves: the recorder ring
         # entry below carries the same id, so a bridged exemplar from a
         # cross-thread operation links to its /debug/traces record

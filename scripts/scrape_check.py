@@ -129,13 +129,11 @@ def main(argv=None) -> int:
         "janus_engine_hd_bytes_total",
         "janus_engine_resident_flushes_total",
         "janus_engine_prestage_total",
-        # continuous profiler + device cost ledger + boot timeline
-        # (ISSUE 13) — registered at import in every binary
+        # continuous profiler + boot timeline (ISSUE 13) — registered
+        # at import in every binary
         "janus_profiler_samples_total",
         "janus_profiler_threads",
         "janus_profiler_overhead_ratio",
-        "janus_device_cost_seconds_total",
-        "janus_device_cost_us_per_report",
         "janus_boot_phase_seconds",
         # shape-manifest AOT prewarm (ISSUE 14) — registered at import
         # in every binary
@@ -356,20 +354,6 @@ def main(argv=None) -> int:
                         for key in ("vdaf", "dp", "sp", "mesh"):
                             if key not in ent:
                                 errors.append(f"/statusz mesh engine entry missing {key!r}")
-                                break
-                dc = snap.get("device_cost")
-                if not isinstance(dc, dict):
-                    errors.append("/statusz missing the device_cost section")
-                else:
-                    for key in ("entries", "us_per_report"):
-                        if key not in dc:
-                            errors.append(f"/statusz device_cost missing {key!r}")
-                    for ent in dc.get("entries", []) or []:
-                        for key in ("vdaf", "op", "bucket", "dispatches", "rows"):
-                            if key not in ent:
-                                errors.append(
-                                    f"/statusz device_cost entry missing {key!r}"
-                                )
                                 break
                 # telemetry flight recorder (ISSUE 18): every binary
                 # installs it by default; a running recorder whose last

@@ -1,9 +1,8 @@
 """Continuous profiling subsystem (ISSUE 13; janus_tpu/profiler.py):
 the sampling wall-clock profiler (role tagging, window ring, collapsed
-format under hostile names, measured overhead), the per-dispatch
-device cost ledger arithmetic, the boot-phase timeline, the health
-listener endpoints, and the shared stack formatter the device
-watchdog's stalled dumps reuse.
+format under hostile names, measured overhead), the boot-phase
+timeline, the health listener endpoints, and the shared stack
+formatter the device watchdog's stalled dumps reuse.
 """
 
 from __future__ import annotations
@@ -18,7 +17,6 @@ import pytest
 from janus_tpu import profiler as prof
 from janus_tpu.profiler import (
     BootTimeline,
-    DeviceCostLedger,
     ProfilerConfig,
     SamplingProfiler,
     fold_component,
@@ -226,109 +224,6 @@ def test_start_stop_idempotent_and_install_uninstall():
     finally:
         prof.uninstall_profiler()
         prof.PROFILER = old
-
-
-# ---------------------------------------------------------------------------
-# device cost ledger
-# ---------------------------------------------------------------------------
-
-
-def test_cost_ledger_arithmetic_and_gauges():
-    from janus_tpu import metrics as m
-
-    ledger = DeviceCostLedger()
-    # 2 dispatches, 1000 rows, 0.1 s execute -> 100 µs/report
-    ledger.record("count", "aggregate", 32, "execute", 0.1, rows=1000, dispatches=2)
-    # transfers attribute to the same op's rows
-    ledger.record("count", "aggregate", 32, "h2d", 0.05)
-    ledger.record("count", "aggregate", 64, "d2h", 0.02, rows=1000, dispatches=1)
-    us = ledger.us_per_report()
-    assert us["aggregate"]["execute"] == pytest.approx(50.0)  # 0.1s / 2000 rows
-    assert us["aggregate"]["h2d"] == pytest.approx(25.0)
-    assert us["aggregate"]["d2h"] == pytest.approx(10.0)
-    st = ledger.status()
-    by_key = {(e["vdaf"], e["op"], e["bucket"]): e for e in st["entries"]}
-    e32 = by_key[("count", "aggregate", 32)]
-    assert e32["dispatches"] == 2 and e32["rows"] == 1000
-    assert e32["execute_s"] == pytest.approx(0.1)
-    assert e32["h2d_s"] == pytest.approx(0.05)
-    e64 = by_key[("count", "aggregate", 64)]
-    assert e64["d2h_s"] == pytest.approx(0.02)
-    # the module-level ledger feeds the gauges/counters
-    prof.DEVICE_COST.record("count", "ledger_test_op", 32, "compile", 0.5, rows=500, dispatches=1)
-    assert m.device_cost_us_per_report.get(
-        op="ledger_test_op", phase="compile"
-    ) == pytest.approx(1000.0)
-    assert m.device_cost_seconds_total.get(op="ledger_test_op", phase="compile") >= 0.5
-    with pytest.raises(ValueError):
-        ledger.record("count", "aggregate", 32, "warp", 0.1)
-
-
-def test_cost_ledger_fed_by_real_engine_dispatches():
-    """A real (CPU) engine init + aggregate lands compile/execute rows
-    AND the span-hook h2d/d2h attribution in the process ledger."""
-    import numpy as np
-
-    from janus_tpu.aggregator.engine_cache import EngineCache
-    from janus_tpu.vdaf.registry import VdafInstance
-    from janus_tpu.vdaf.testing import make_report_batch, random_measurements
-
-    prof.DEVICE_COST.reset_for_tests()
-    inst = VdafInstance.count()
-    eng = EngineCache(inst, bytes(range(16)))
-    rng = np.random.default_rng(3)
-    n = 8
-    args, _ = make_report_batch(inst, random_measurements(inst, n, rng), seed=1)
-    nonce, public, mv, proof, blind0, _, _ = args
-    out0, _, _, _ = eng.leader_init(nonce, public, mv, proof, blind0)
-    eng.aggregate(out0, np.ones(n, dtype=bool))
-    st = prof.DEVICE_COST.status()
-    ops = {e["op"] for e in st["entries"]}
-    assert "leader_init" in ops and "aggregate" in ops
-    li = [e for e in st["entries"] if e["op"] == "leader_init"]
-    # first dispatch of the bucket is the compile; rows counted
-    assert sum(e["compile_s"] for e in li) > 0
-    assert sum(e["rows"] for e in li) == n
-    # the put/fetch span hooks attributed transfer time with the bucket
-    assert sum(e["h2d_s"] + e["d2h_s"] for e in li) > 0
-    assert all(e["bucket"] > 0 for e in li)
-    us = prof.DEVICE_COST.us_per_report()
-    assert us["aggregate"].get("execute", 0) > 0 or us["aggregate"].get("compile", 0) > 0
-
-
-def test_cost_ledger_compile_attribution_tracks_jit_specialization():
-    """The resident aggregate_pending path and the classic aggregate
-    share op="aggregate" in the engine counters AND the same row
-    bucket, but compile different programs — each ledger row must book
-    its own first dispatch as phase="compile" (keyed by the jit
-    specialization, not the engine-metric (op, bucket))."""
-    import numpy as np
-
-    from janus_tpu.aggregator.engine_cache import EngineCache
-    from janus_tpu.vdaf.registry import VdafInstance
-    from janus_tpu.vdaf.testing import make_report_batch, random_measurements
-
-    prof.DEVICE_COST.reset_for_tests()
-    inst = VdafInstance.count()
-    eng = EngineCache(inst, bytes(range(16)))
-    rng = np.random.default_rng(5)
-    n = 8
-    args, _ = make_report_batch(inst, random_measurements(inst, n, rng), seed=2)
-    nonce, public, mv, proof, blind0, _, _ = args
-    out0, _, _, _ = eng.leader_init(nonce, public, mv, proof, blind0)
-    # resident path FIRST marks the (op="aggregate", row bucket)
-    eng.aggregate_pending(out0, np.zeros(n, dtype=np.int32), 2)
-    # ...the classic path's first dispatch still compiles its own
-    # program and must NOT book that wall time as execute
-    eng.aggregate(out0, np.ones(n, dtype=bool))
-    st = prof.DEVICE_COST.status()
-    by_op = {}
-    for e in st["entries"]:
-        agg = by_op.setdefault(e["op"], {"compile_s": 0.0, "execute_s": 0.0})
-        agg["compile_s"] += e["compile_s"]
-        agg["execute_s"] += e["execute_s"]
-    assert by_op["aggregate_pending"]["compile_s"] > 0
-    assert by_op["aggregate"]["compile_s"] > 0, by_op
 
 
 # ---------------------------------------------------------------------------

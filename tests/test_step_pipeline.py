@@ -337,3 +337,113 @@ def test_abandon_after_max_attempts_still_applies(pair):
         pipe.close()
     assert metrics.job_cancel_counter.get(kind="aggregation") == before + 1
     assert _agg_job_states(pair["leader_ds"]) == {"abandoned": 1}
+
+
+def _queue_wait(stage):
+    """(count, sum) of janus_step_pipeline_queue_wait_seconds{stage}."""
+    doc = metrics.REGISTRY.snapshot()["janus_step_pipeline_queue_wait_seconds"]
+    for sample in doc["samples"]:
+        if sample["labels"] == {"stage": stage}:
+            return sample["count"], sample["sum"]
+    return 0, 0.0
+
+
+class _StubDriver:
+    """The stage methods StepPipeline calls, with a device_init that
+    blocks the lane until `gate` opens."""
+
+    def __init__(self, gate):
+        from types import SimpleNamespace
+
+        self.gate = gate
+        self.cfg = SimpleNamespace(maximum_attempts_before_failure=10)
+
+    def read_job(self, acquired):
+        from types import SimpleNamespace
+
+        jobrow = SimpleNamespace(state=AggregationJobState.IN_PROGRESS, trace_context=None)
+        return "task", jobrow, [], []
+
+    def _lease_deadline(self, acquired):
+        return None
+
+    def plan_step(self, acquired, task, jobrow, ras):
+        return "init", []
+
+    def stage_init(self, acquired, task, jobrow, rows, reports):
+        from types import SimpleNamespace
+
+        return SimpleNamespace(engine=None, prestaged=None, multi_round=False)
+
+    def device_init(self, st):
+        assert self.gate.wait(10)
+
+    def http_init(self, st):
+        pass
+
+    def device_accumulate(self, st):
+        pass
+
+    def commit_finish(self, st):
+        pass
+
+    def handle_step_error(self, acquired, e):
+        return False
+
+
+def test_queue_wait_observed_for_blocked_device_lane_and_staging():
+    """Job 1 holds the one-worker device lane; job 2, staged, waits in
+    the lane's queue; job 3 waits for a staging-window slot. Both waits
+    are observed from their own stamps once the lane opens."""
+    import threading
+    from types import SimpleNamespace
+
+    gate = threading.Event()
+    pipe = StepPipeline(_StubDriver(gate), StepPipelineConfig(prefetch_depth=2))
+    device0, staging0 = _queue_wait("device"), _queue_wait("staging")
+    try:
+        futs = [
+            pipe.submit(SimpleNamespace(job_id=i, lease=SimpleNamespace(attempts=1)))
+            for i in range(3)
+        ]
+        time.sleep(0.25)
+        gate.set()
+        for f in futs:
+            f.result(timeout=30)
+    finally:
+        pipe.close()
+    n_dev, s_dev = (a - b for a, b in zip(_queue_wait("device"), device0))
+    n_stg, s_stg = (a - b for a, b in zip(_queue_wait("staging"), staging0))
+    assert n_dev == 6  # init + accumulate per job
+    assert s_dev >= 0.2  # job 2 queued behind the blocked lane
+    assert n_stg == 3  # every hot-path job passes the staging window
+    assert s_stg >= 0.2  # job 3 waited for a slot
+
+
+def test_pipelined_job_observes_queue_waits_and_helper_init_stages(pair):
+    """A real pipelined job observes a queue wait at each of its
+    stages, and its helper's init spans feed the init-stage histogram."""
+    vdaf = VdafInstance.count()
+    leader_task, _, _ = provision(pair, vdaf)
+    http = _upload(pair, leader_task, vdaf, [1, 0, 1])
+    assert _make_jobs(pair) == 1
+
+    def init_stages():
+        doc = metrics.REGISTRY.snapshot()["janus_aggregate_init_stage_seconds"]
+        return {s["labels"]["stage"]: s["count"] for s in doc["samples"]}
+
+    waits0 = {s: _queue_wait(s)[0] for s in ("read", "device", "http", "commit", "staging")}
+    stages0 = init_stages()
+    drv = AggregationJobDriver(pair["leader_ds"], http)
+    pipe = StepPipeline(drv, StepPipelineConfig())
+    try:
+        jd = JobDriver(JobDriverConfig(), drv.acquirer(), drv.stepper, pipeline=pipe)
+        assert jd.run_once() == 1
+    finally:
+        pipe.close()
+    assert _agg_job_states(pair["leader_ds"]) == {"finished": 1}
+    waits = {s: _queue_wait(s)[0] - n for s, n in waits0.items()}
+    assert waits == {"read": 1, "device": 2, "http": 1, "commit": 1, "staging": 1}
+    stages = init_stages()
+    for stage in ("hpke_stage", "replay_tx", "columnar", "accumulate", "write_tx"):
+        assert stages.get(stage, 0) > stages0.get(stage, 0), stage
