@@ -71,13 +71,14 @@ from ..vdaf.wire import (
     PP_FINISH,
     PP_INITIALIZE,
     Prio3Wire,
-    decode_field_rows,
     decode_pingpong,
     encode_field_rows,
     encode_pingpong,
     encode_pingpong_share_column,
     flat_scatter_indices,
     pingpong_finish_frame_matches,
+    put_field_row,
+    rows_in_range,
     seeds_to_lanes,
 )
 from .accumulator import (
@@ -87,7 +88,7 @@ from .accumulator import (
     fixed_size_batch_id,
     group_batch_buckets,
 )
-from .engine_cache import DeviceHangError, EngineCache, engine_cache
+from .engine_cache import DeviceHangError, EngineCache, bucket_size, engine_cache
 
 log = logging.getLogger(__name__)
 
@@ -367,41 +368,64 @@ class AggregationJobDriver:
             )
 
     def _stage_pending(self, task, wire, engine, pending, reports):
-        """Columnar staging of stored leader shares -> device-ready
-        arrays + per-report failure marks."""
+        """Single-pass staging of a job's stored leader shares into
+        device-ready arrays + per-report failure marks. Each sealed share
+        is decrypted and its u64 words written straight into zeroed limb
+        columns of the job's bucket row count; one vectorised range
+        check follows. The engine gets the first n rows as views, so
+        `pad_args` hands the padded columns to the device without a
+        copy."""
+        from ..trace import span
+
         n = len(pending)
-        meas_rows: list[bytes | None] = [None] * n
-        proof_rows: list[bytes | None] = [None] * n
-        blind_rows: list[bytes | None] = [None] * n
+        circ = wire.circ
+        jf = engine.p3.jf
+        # the padded row count the engine dispatches (a job past the
+        # engine's cap is chunked from its n rows, so it gets no padding)
+        rows = max(n, bucket_size(n, getattr(engine, "bucket_cap", None)))
+        meas = tuple(np.zeros((rows, circ.input_len), np.uint64) for _ in range(jf.LIMBS))
+        proof = tuple(np.zeros((rows, circ.proof_len), np.uint64) for _ in range(jf.LIMBS))
+        jr_at = (circ.input_len + circ.proof_len) * jf.LIMBS  # blind's first word
+        blind_lanes = np.zeros((n, 2), np.uint64) if wire.uses_jr else None
+        shaped = np.zeros(n, dtype=bool)  # share decrypted at its exact length
         part_rows0: list[bytes | None] = [None] * n
         part_rows1: list[bytes | None] = [None] * n
         failed = [None] * n  # PrepareError or None
-        circ = wire.circ
         idx_rows: list | None = [None] * n if wire.sparse else None
-        mlen = circ.input_len * wire.enc_size
-        plen = circ.proof_len * wire.enc_size
-        for i, ra in enumerate(pending):
-            rep = reports.get(ra.report_id.data)
-            if rep is None:
-                failed[i] = PrepareError.REPORT_DROPPED
-                continue
-            payload = rep.leader_input_share
-            if len(payload) != wire.leader_share_len:
-                failed[i] = PrepareError.INVALID_MESSAGE
-                continue
-            meas_rows[i] = payload[:mlen]
-            proof_rows[i] = payload[mlen : mlen + plen]
-            if wire.uses_jr:
-                blind_rows[i] = payload[mlen + plen :]
-                try:
-                    parts = wire.decode_public_share(rep.public_share)
-                    part_rows0[i], part_rows1[i] = parts
-                    if idx_rows is not None:
-                        # validated PUBLIC block indices (the sparse
-                        # decode rejects out-of-range / unsorted rows)
-                        idx_rows[i] = parts.indices
-                except DecodeError:
+        with span("driver.share_decode", batch=n):
+            for i, ra in enumerate(pending):
+                rep = reports.get(ra.report_id.data)
+                if rep is None:
+                    failed[i] = PrepareError.REPORT_DROPPED
+                    continue
+                payload = rep.leader_input_share  # decrypts; ValueError fails the step
+                if len(payload) != wire.leader_share_len:
                     failed[i] = PrepareError.INVALID_MESSAGE
+                    continue
+                words = np.frombuffer(payload, dtype="<u8")
+                put_field_row(meas, i, words[: circ.input_len * jf.LIMBS])
+                put_field_row(proof, i, words[circ.input_len * jf.LIMBS : jr_at])
+                shaped[i] = True
+                if wire.uses_jr:
+                    blind_lanes[i] = words[jr_at:]
+                    try:
+                        parts = wire.decode_public_share(rep.public_share)
+                        part_rows0[i], part_rows1[i] = parts
+                        if idx_rows is not None:
+                            # validated PUBLIC block indices (the sparse
+                            # decode rejects out-of-range / unsorted rows)
+                            idx_rows[i] = parts.indices
+                    except DecodeError:
+                        failed[i] = PrepareError.INVALID_MESSAGE
+            meas = tuple(c[:n] for c in meas)
+            proof = tuple(c[:n] for c in proof)
+            ok_m = shaped & rows_in_range(meas, jf.MODULUS)
+            ok_p = shaped & rows_in_range(proof, jf.MODULUS)
+            # zero out-of-range rows so device math stays in range
+            for cols, col_ok in ((meas, ok_m), (proof, ok_p)):
+                if not col_ok.all():
+                    for c in cols:
+                        c[~col_ok] = 0
 
         # test-only fake failure injection on the leader init path
         # (the reference's dummy_vdaf prep_init_fn hook)
@@ -410,19 +434,14 @@ class AggregationJobDriver:
                 if failed[i] is None:
                     failed[i] = PrepareError.VDAF_PREP_ERROR
 
-        jf = engine.p3.jf
-        meas, ok_m = decode_field_rows(jf, meas_rows, circ.input_len)
-        proof, ok_p = decode_field_rows(jf, proof_rows, circ.proof_len)
         nonce_lanes, _ = seeds_to_lanes([ra.report_id.data for ra in pending])
-        ok = ok_m & ok_p & np.array([f is None for f in failed])
+        ok = ok_m & ok_p & np.array([f is None for f in failed], dtype=bool)
         if wire.uses_jr:
-            blind_lanes, ok_b = seeds_to_lanes(blind_rows)
             p0, ok_p0 = seeds_to_lanes(part_rows0)
             p1, ok_p1 = seeds_to_lanes(part_rows1)
-            ok = ok & ok_b & ok_p0 & ok_p1
+            ok = ok & ok_p0 & ok_p1
             public_parts = np.stack([p0, p1], axis=1)
         else:
-            blind_lanes = None
             public_parts = None
         if idx_rows is not None:
             block_idx = np.full((n, circ.max_blocks), -1, dtype=np.int32)
@@ -431,6 +450,9 @@ class AggregationJobDriver:
                     block_idx[i] = row
         else:
             block_idx = None
+        n_ok = int(ok.sum())
+        metrics.leader_shares_decoded_total.add(n_ok, result="ok")
+        metrics.leader_shares_decoded_total.add(n - n_ok, result="rejected")
         return meas, proof, nonce_lanes, blind_lanes, public_parts, ok, failed, block_idx
 
     # --- the step (reference :102-726), decomposed into the stage
@@ -446,12 +468,11 @@ class AggregationJobDriver:
             task = tx.get_task(acquired.task_id)
             job = tx.get_aggregation_job(acquired.task_id, acquired.job_id)
             ras = tx.get_report_aggregations_for_job(acquired.task_id, acquired.job_id)
-            reports = {}
-            for ra in ras:
-                if ra.state == ReportAggregationState.START:
-                    reports[ra.report_id.data] = tx.get_client_report(
-                        acquired.task_id, ra.report_id
-                    )
+            # the START rows' reports with their leader shares still
+            # sealed: staging opens each outside this transaction
+            reports = tx.get_sealed_client_reports_for_job(
+                acquired.task_id, acquired.job_id
+            )
             return task, job, ras, reports
 
         from ..trace import span

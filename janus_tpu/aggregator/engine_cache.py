@@ -105,12 +105,36 @@ def _cut_rows(a, s: int, e: int):
     return a[s:e]
 
 
+def _padded_base(arr, b: int):
+    """The array of b rows that `arr` is the leading rows of, where that
+    array's other rows are all zero: then it is exactly what padding
+    `arr` to b rows makes, and no copy is needed. The driver stages its
+    columns so (`_stage_pending`); anything else gets None."""
+    base = getattr(arr, "base", None)
+    if (
+        not isinstance(base, np.ndarray)
+        or base.ndim != arr.ndim
+        or base.shape[0] != b
+        or base.shape[1:] != arr.shape[1:]
+        or base.dtype != arr.dtype
+        or not base.flags.c_contiguous
+        or not arr.flags.c_contiguous
+        or arr.ctypes.data != base.ctypes.data
+        or base[arr.shape[0] :].any()
+    ):
+        return None
+    return base
+
+
 def _pad(arr, b: int):
     if arr is None:
         return None
     pad = b - arr.shape[0]
     if pad == 0:
         return arr
+    base = _padded_base(arr, b)
+    if base is not None:
+        return base
     widths = [(0, pad)] + [(0, 0)] * (arr.ndim - 1)
     return np.pad(np.asarray(arr), widths)
 

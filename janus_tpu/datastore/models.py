@@ -10,7 +10,7 @@ OutstandingBatch:1412, Batch:1473).
 from __future__ import annotations
 
 import enum
-from dataclasses import dataclass, replace
+from dataclasses import dataclass, field, replace
 
 from ..messages import (
     AggregationJobId,
@@ -82,6 +82,33 @@ class LeaderStoredReport:
     public_share: bytes
     leader_input_share: bytes  # decoded leader share, encrypted at rest
     helper_encrypted_input_share: HpkeCiphertext
+
+
+@dataclass(frozen=True)
+class SealedLeaderReport:
+    """A stored leader report as the aggregation job read fetches it:
+    the leader input share is still sealed under the datastore Crypter,
+    so the read transaction does no decryption. `leader_input_share`
+    opens it on each access, through the same Crypter call
+    `get_client_report` makes."""
+
+    task_id: TaskId
+    report_id: ReportId
+    client_time: Time
+    public_share: bytes
+    sealed_leader_input_share: bytes
+    helper_encrypted_input_share: HpkeCiphertext
+    crypter: object = field(repr=False, compare=False)
+
+    @property
+    def leader_input_share(self) -> bytes:
+        """The plaintext share; raises ValueError where no key opens it."""
+        return self.crypter.decrypt(
+            "client_reports",
+            self.task_id.data + self.report_id.data,
+            "leader_input_share",
+            self.sealed_leader_input_share,
+        )
 
 
 @dataclass(frozen=True)

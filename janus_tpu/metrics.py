@@ -722,6 +722,18 @@ aggregate_init_stage_seconds = REGISTRY.histogram(
     "the helper.<stage> spans",
     buckets=FINE_BUCKETS,
 )
+leader_share_decode_seconds = REGISTRY.histogram(
+    "janus_leader_share_decode_seconds",
+    "one Prio3 init job's single staging pass over its stored leader "
+    "shares: decrypt each into the bucket-padded limb columns, then the "
+    "vectorised range check; fed by the driver.share_decode span",
+    buckets=FINE_BUCKETS,
+)
+leader_shares_decoded_total = REGISTRY.counter(
+    "janus_leader_shares_decoded_total",
+    "reports through the leader's staging pass, by verdict "
+    '(result="ok|rejected": rejected is dropped, malformed or out of range)',
+)
 device_lane_busy_ratio = REGISTRY.gauge(
     "janus_device_lane_busy_ratio",
     "fraction of wall time the pipeline's serialized device lane spent "
@@ -1277,6 +1289,8 @@ def _register_span_bridges() -> None:
     register_span_metric(
         "pipeline.staging_wait", step_pipeline_queue_wait_seconds, labels={"stage": "staging"}
     )
+    # the leader's single staging pass over a job's stored shares
+    register_span_metric("driver.share_decode", leader_share_decode_seconds)
     # the helper's aggregate-init stages
     for stage in ("hpke_stage", "replay_tx", "columnar", "accumulate", "write_tx"):
         register_span_metric(

@@ -147,17 +147,50 @@ def encode_field_rows(jf, value) -> list[bytes]:
     return [row.tobytes() for row in u8]
 
 
-def lanes_in_range(lanes: np.ndarray, modulus: int, limbs: int) -> np.ndarray:
-    """Element-wise `value < modulus` over little-endian u64 lane arrays
-    shaped [..., n*limbs]. Single home for the two-limb lexicographic
-    compare so upload validation and driver staging can't diverge."""
-    if limbs == 1:
-        return lanes < np.uint64(modulus)
-    r = lanes.reshape(lanes.shape[:-1] + (-1, 2))
-    lo, hi = r[..., 0], r[..., 1]
+def limbs_in_range(limbs, modulus: int) -> np.ndarray:
+    """Element-wise `value < modulus` over a field value's u64 limb
+    columns (`(lanes,)` for a one-limb field, `(lo, hi)` for two). Single
+    home for the two-limb lexicographic compare, so upload validation,
+    the helper and the driver's staging can't diverge."""
+    if len(limbs) == 1:
+        return limbs[0] < np.uint64(modulus)
+    lo, hi = limbs
     p_lo = np.uint64(modulus & 0xFFFFFFFFFFFFFFFF)
     p_hi = np.uint64(modulus >> 64)
     return (hi < p_hi) | ((hi == p_hi) & (lo < p_lo))
+
+
+def lanes_in_range(lanes: np.ndarray, modulus: int, limbs: int) -> np.ndarray:
+    """`limbs_in_range` over little-endian u64 lane arrays shaped
+    [..., n*limbs], the limbs of each element adjacent."""
+    if limbs == 1:
+        return limbs_in_range((lanes,), modulus)
+    r = lanes.reshape(lanes.shape[:-1] + (-1, 2))
+    return limbs_in_range((r[..., 0], r[..., 1]), modulus)
+
+
+def rows_in_range(limbs, modulus: int) -> np.ndarray:
+    """[rows] mask of limb columns [rows, n]: every element of the row
+    is below `modulus`. A row whose top limb stays below the modulus's
+    top limb is in range outright (one max-reduction over that limb);
+    only the other rows go through the full `limbs_in_range` compare,
+    so the answer is that compare's."""
+    top = np.uint64(modulus >> (64 * (len(limbs) - 1)))
+    ok = limbs[-1].max(axis=-1) < top
+    suspect = np.flatnonzero(~ok)
+    if suspect.size:
+        ok[suspect] = limbs_in_range(tuple(c[suspect] for c in limbs), modulus).all(axis=-1)
+    return ok
+
+
+def put_field_row(limbs, i: int, words: np.ndarray) -> None:
+    """Write one element vector, as its little-endian u64 words (the
+    limbs of each element adjacent), into row i of the limb columns."""
+    if len(limbs) == 1:
+        limbs[0][i] = words
+    else:
+        limbs[0][i] = words[0::2]
+        limbs[1][i] = words[1::2]
 
 
 def decode_field_rows(jf, rows: list[bytes], n: int):

@@ -122,6 +122,55 @@ def test_client_reports(eph):
     assert deleted == (0, 2)
 
 
+def test_sealed_client_reports_for_job(eph):
+    """One statement fetches a job's START reports with their leader
+    shares still sealed; each opens to what was stored. Reports past
+    START, and a START row whose report is gone, are absent."""
+    ds = eph.datastore
+    task = mktask()
+    ds.run_tx(lambda tx: tx.put_task(task))
+    job = _aggjob(task)
+    ds.run_tx(lambda tx: tx.put_aggregation_job(job))
+    reps = [_report(task, i, 1000 + i) for i in range(4)]
+    for rep in reps[:3]:  # report 3 is not stored
+        ds.run_tx(lambda tx, rep=rep: tx.put_client_report(rep))
+    states = [
+        ReportAggregationState.START,
+        ReportAggregationState.START,
+        ReportAggregationState.FINISHED,
+        ReportAggregationState.START,
+    ]
+    ds.run_tx(
+        lambda tx: [
+            tx.put_report_aggregation(
+                ReportAggregationModel(
+                    task.task_id, job.job_id, rep.report_id, rep.client_time, i, state
+                )
+            )
+            for i, (rep, state) in enumerate(zip(reps, states))
+        ]
+    )
+    got = ds.run_tx(
+        lambda tx: tx.get_sealed_client_reports_for_job(task.task_id, job.job_id)
+    )
+    assert sorted(got) == sorted(r.report_id.data for r in reps[:2])
+    for rep in reps[:2]:
+        sealed = got[rep.report_id.data]
+        assert sealed.sealed_leader_input_share != rep.leader_input_share
+        assert sealed.leader_input_share == rep.leader_input_share
+        assert (
+            sealed.report_id,
+            sealed.client_time,
+            sealed.public_share,
+            sealed.helper_encrypted_input_share,
+        ) == (
+            rep.report_id,
+            rep.client_time,
+            rep.public_share,
+            rep.helper_encrypted_input_share,
+        )
+
+
 def _aggjob(task, jid=1):
     return AggregationJobModel(
         task.task_id,
